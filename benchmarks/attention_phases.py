@@ -14,9 +14,7 @@ which is exactly what a committed per-repo baseline is for.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -30,72 +28,62 @@ SPECS = ("softmax", "fastmax2", "fastmax2-kernel", "hybrid2-kernel")
 
 # TP>1 decode cell: the shard_map-wrapped Pallas decode kernel vs the jnp
 # feature-TP moment step it replaced as the tensor-parallel serving path.
-# Runs in a subprocess so this process keeps its 1-device view: the child
-# forces 8 host devices and decodes under a (data=2, model=4) mesh with kv
-# heads NOT dividing 'model' (the GQA feature-TP regime of the production
-# configs). Interpret-mode kernels — within-machine trend tracking only,
-# like every row in this suite.
-_TP_SUBPROC = r"""
-import os, json, time
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-    + " --xla_force_host_platform_device_count=8").strip()
-import jax, jax.numpy as jnp, numpy as np
-from repro.attention import AttentionSpec, init_state, prefill, step
-from repro.launch.mesh import make_test_mesh
+# Runs in THIS process on the devices present (a child that needs the chip
+# would fight the parent for it): a (data=n/4, model=4) mesh with kv heads
+# NOT dividing 'model' (the GQA feature-TP regime of the production
+# configs). Dropped where fewer than 4 devices exist.
+def _bench_tp_decode(*, quick: bool):
+    import time
 
-b, hq, hkv, n, d, dv, iters, steps = {shape}
-spec = AttentionSpec(family="fastmax", p=2, impl="kernel", chunk_size=64)
-rng = np.random.default_rng(0)
-mkq = lambda m: (jnp.asarray(rng.normal(size=(b, hq, m, d)), jnp.float32),
-                 jnp.asarray(rng.normal(size=(b, hkv, m, d)), jnp.float32),
-                 jnp.asarray(rng.normal(size=(b, hkv, m, dv)), jnp.float32))
-q, k, v = mkq(n)
-q1, k1, v1 = mkq(1)
-mesh = make_test_mesh((2, 4), ("data", "model"))
-from repro.kernels import autotune
-res = {{}}
-with mesh:
-    for key, env in (("decode_us", "1"), ("decode_jnp_us", "0")):
-        os.environ["REPRO_DECODE_KERNEL"] = env
-        st = init_state(spec, batch=b, n_kv_heads=hkv, q_head_dim=d,
-                        v_head_dim=dv, max_len=n + 1)
-        _, st = prefill(q, k, v, spec, state=st)
-        fn = jax.jit(lambda st, q, k, v: step(st, q, k, v, spec))
-        o, _ = fn(st, q1, k1, v1)
-        o.block_until_ready()
-        ts = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                o, _ = fn(st, q1, k1, v1)
+    import jax
+    import jax.numpy as jnp
+
+    from repro.attention import AttentionSpec, init_state, prefill, step
+    from repro.kernels import autotune
+    from repro.launch.mesh import make_test_mesh
+
+    n_dev = len(jax.devices())
+    if n_dev < 4 or n_dev % 4:
+        print(f"attn_phases: tp-decode cell needs a multiple of 4 devices; "
+              f"this process has {n_dev} -> cell not run", file=sys.stderr)
+        return None
+    b, hq, hkv, n, d, dv, iters, steps = (
+        (2, 4, 2, 128, 16, 16, 3, 8) if quick
+        else (4, 8, 2, 1024, 64, 64, 5, 16))
+    b *= n_dev // 4
+    spec = AttentionSpec(family="fastmax", p=2, impl="kernel", chunk_size=64)
+    rng = np.random.default_rng(0)
+
+    def mkq(m):
+        return (jnp.asarray(rng.normal(size=(b, hq, m, d)), jnp.float32),
+                jnp.asarray(rng.normal(size=(b, hkv, m, d)), jnp.float32),
+                jnp.asarray(rng.normal(size=(b, hkv, m, dv)), jnp.float32))
+
+    q, k, v = mkq(n)
+    q1, k1, v1 = mkq(1)
+    mesh = make_test_mesh((n_dev // 4, 4), ("data", "model"))
+    res = {}
+    with mesh:
+        for key, env in (("decode_us", "1"), ("decode_jnp_us", "0")):
+            os.environ["REPRO_DECODE_KERNEL"] = env
+            st = init_state(spec, batch=b, n_kv_heads=hkv, q_head_dim=d,
+                            v_head_dim=dv, max_len=n + 1)
+            _, st = prefill(q, k, v, spec, state=st)
+            fn = jax.jit(lambda st, q, k, v: step(st, q, k, v, spec))
+            o, _ = fn(st, q1, k1, v1)
             o.block_until_ready()
-            ts.append((time.perf_counter() - t0) / steps)
-        res[key] = min(ts) * 1e6
-snap = autotune.snapshot_lookups()
-res["schedule"] = {{r["key"]: r["schedule"] for r in snap}}
-res["autotune_cache"] = {{r["key"]: r["cache"] for r in snap}}
-print(json.dumps(res))
-"""
-
-
-def _bench_tp_decode(*, quick: bool) -> dict:
-    shape = ((2, 4, 2, 128, 16, 16, 3, 8) if quick
-             else (4, 8, 2, 1024, 64, 64, 5, 16))
-    out = subprocess.run(
-        [sys.executable, "-c", _TP_SUBPROC.format(shape=shape)],
-        capture_output=True, text=True, timeout=900,
-        env={**os.environ, "PYTHONPATH": "src"},
-    )
-    if out.returncode != 0:
-        raise RuntimeError(f"tp-decode subprocess failed: "
-                           f"{out.stderr[-800:]}")
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    # the subprocess runs on forced HOST devices, so its kernels are
-    # interpret-mode even when this process sits on a TPU — label the cell
-    # so the regression check never compares it against a compiled-TPU
-    # baseline (or vice versa)
-    res["interpret"] = True
-    res["hardware"] = "cpu-interpret"
+            ts = []
+            for _ in range(iters):
+                t0 = time.perf_counter()
+                for _ in range(steps):
+                    o, _ = fn(st, q1, k1, v1)
+                o.block_until_ready()
+                ts.append((time.perf_counter() - t0) / steps)
+            res[key] = min(ts) * 1e6
+    snap = autotune.snapshot_lookups()
+    res["schedule"] = {r["key"]: r["schedule"] for r in snap}
+    res["autotune_cache"] = {r["key"]: r["cache"] for r in snap}
+    res["hardware"] = autotune.hardware_label()
     return res
 
 
@@ -171,15 +159,11 @@ def collect(quick: bool = True) -> dict:
     os.environ.setdefault("REPRO_AUTOTUNE", "offline")
     try:
         suites = {name: _bench_spec(name, **shape) for name in SPECS}
-        # TP>1 decode: shard_map kernel vs the jnp feature-TP step
-        # (subprocess with 8 forced host devices — inherits the autotune
-        # env above so its shard-local lookups record provenance too;
-        # fail-soft so a broken child doesn't take the whole suite down)
-        try:
-            suites["fastmax2-kernel-tp4"] = _bench_tp_decode(quick=quick)
-        except Exception as e:  # noqa: BLE001
-            print(f"attn_phases: tp-decode cell skipped ({e})",
-                  file=sys.stderr)
+        # TP>1 decode: shard_map kernel vs the jnp feature-TP step, in
+        # process on the devices present (None where there are too few)
+        tp = _bench_tp_decode(quick=quick)
+        if tp is not None:
+            suites["fastmax2-kernel-tp4"] = tp
     finally:
         for var, val in prev.items():
             if val is None:

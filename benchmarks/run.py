@@ -70,15 +70,19 @@ def main() -> None:
         suites.pop("attn_phases", None)
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         t0 = time.time()
         try:
             for row in fn(quick=quick):
                 print(row, flush=True)
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — report, then fail the run
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"{name}/elapsed,{(time.time() - t0) * 1e6:.0f},",
               flush=True)
+    if failed:
+        raise SystemExit(f"benchmarks failed: {', '.join(failed)}")
 
     if args.json:
         fresh = attention_phases.collect(quick=quick)

@@ -294,23 +294,14 @@ def prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       new_len, nm)
         return o.astype(q.dtype), AttnState(kv=nkv,
                                             moments=Moments(*final))
-    if offset is not None:
-        # resumable chunked prefill: seed the jnp scan with the carried
-        # moments (the Pallas prefill kernels take no initial carry; decode
-        # steps after the handoff still route to the kernels)
-        _log_once("prefill: resumable (offset) chunk -> jnp moment scan")
-        fs = feature_shard_flag(k.shape[1])
-        o, final = _causal_scan(
-            qh, kh, v, p=spec.p, chunk_size=spec_r.chunk_size,
-            kv_mask=kv_mask, denom_eps=spec.denom_eps, feature_shard=fs,
-            init=state.moments)
-        return o.astype(q.dtype), AttnState(kv=None, moments=Moments(*final))
+    # resumable chunked prefill seeds the scan with the carried moments
+    init = None if offset is None else state.moments
     if use_decode_kernel(spec):
         # one kernel launch yields outputs AND the final carry — the
         # prefill→decode handoff without recomputing moments
         from repro.kernels import ops as kernel_ops
         mesh, plan = _kernel_plan(q, k, v)
-        if plan is not None:
+        if plan is not None and init is None:
             from repro.kernels.sharded import fastmax_prefill_sharded
             o, state = fastmax_prefill_sharded(
                 qh, kh, v, p=spec.p, chunk_size=spec_r.chunk_size,
@@ -318,20 +309,30 @@ def prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             return o.astype(q.dtype), AttnState(kv=None,
                                                 moments=Moments(*state))
         if mesh is None:
+            _log_once("prefill: fastmax-kernel " + (
+                "whole-prompt kernel" if init is None
+                else "resumable (offset) chunk, kernel seeded with the "
+                     "carried moments"))
             o, state = kernel_ops.fastmax_prefill_kernel(
                 qh, kh, v, p=spec.p, chunk_size=spec_r.chunk_size,
-                denom_eps=spec.denom_eps, kv_mask=kv_mask)
+                denom_eps=spec.denom_eps, kv_mask=kv_mask,
+                init_state=None if init is None else tuple(init))
             return o.astype(q.dtype), AttnState(kv=None,
                                                 moments=Moments(*state))
         _log_once(
-            "decode: fastmax kernel unpartitionable over 'model' "
-            "(kv heads and Dv both indivisible) -> jnp feature-TP scan")
+            "prefill: fastmax kernel " + (
+                "unpartitionable over 'model' (kv heads and Dv both "
+                "indivisible)" if plan is None
+                else "resumable (offset) chunk under a mesh")
+            + " -> jnp moment scan")
+    elif init is not None:
+        _log_once("prefill: resumable (offset) chunk -> jnp moment scan")
     # the jnp chunked scan is sharding-aware: under feature-TP the stacked
     # chunks are pinned and the carry constrained (see _causal_scan)
     fs = feature_shard_flag(k.shape[1])
     o, final = _causal_scan(
         qh, kh, v, p=spec.p, chunk_size=spec_r.chunk_size, kv_mask=kv_mask,
-        denom_eps=spec.denom_eps, feature_shard=fs)
+        denom_eps=spec.denom_eps, feature_shard=fs, init=init)
     return o.astype(q.dtype), AttnState(kv=None, moments=final)
 
 

@@ -68,12 +68,13 @@ import time
 from typing import NamedTuple, Optional
 
 from repro.kernels.tiling import (BWD_BLK_BUDGET, FWD_BLK_BUDGET,
-                                  KERNEL_BM_BUDGET, divisors, pick_blk,
-                                  pick_bm)
+                                  KERNEL_BM_BUDGET, VMEM_LIMIT_BYTES,
+                                  divisors, lane_tileable, pick_blk, pick_bm)
 
 __all__ = ["Schedule", "ShapeKey", "KERNELS", "autotune_mode",
            "default_schedule", "candidate_schedules", "cost_model",
            "measure", "tune", "lookup_schedule", "load_cache", "save_cache",
+           "tpu_tileable",
            "key_str", "hardware_label", "clear_lookups", "snapshot_lookups",
            "gate_keys", "build_gate_entries", "DEFAULT_CACHE",
            "CACHE_VERSION"]
@@ -89,7 +90,7 @@ DEFAULT_CACHE = os.path.join(os.path.dirname(__file__),
 # only the deterministic RANKING of candidates matters.
 MXU_FLOPS = 197e12          # peak matmul flop/s
 HBM_BW = 819e9              # bytes/s
-VMEM_BYTES = 16 * 2 ** 20   # per-core scratch + working-set ceiling
+VMEM_BYTES = VMEM_LIMIT_BYTES  # the kernels' scoped-VMEM limit
 GRID_STEP_S = 2e-6          # fixed per-grid-program overhead
 MEGACORE = 2                # "parallel" grid dims split across cores
 
@@ -168,20 +169,23 @@ def candidate_schedules(kernel: str, key: ShapeKey,
                         chunk_size: int = 128) -> list:
     """The bounded sweep set for one kernel/shape (always contains the
     untuned default). Every emitted schedule is valid: bm | D, blk | Dv,
-    and the scratch tuples fit the VMEM feasibility cap — the parity tests
-    sweep exactly this list against the default schedule."""
+    both tile on the TPU (`tpu_tileable`), and the scratch tuples fit the
+    VMEM feasibility cap — the parity tests sweep exactly this list
+    against the default schedule."""
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r}; expected {KERNELS}")
     d, dv, n = key.d, key.dv, key.n
 
     # bm: largest 3 divisors of D whose [bm*D, blk] tile stays MXU-sized
-    bms = [bm for bm in divisors(d) if bm * d <= 4 * KERNEL_BM_BUDGET][-3:]
+    bms = [bm for bm in divisors(d) if bm * d <= 4 * KERNEL_BM_BUDGET
+           and _rows_tileable(bm, d)][-3:]
 
     if kernel in ("causal_fwd", "causal_bwd", "hybrid_fwd"):
         ntuples = 2 if kernel == "causal_bwd" else 1
         cap = VMEM_BYTES // 2    # leave headroom for the I/O tiles
-        blks = [b for b in divisors(dv)
-                if ntuples * d * d * b * 4 <= cap][-3:] or [1]
+        tileable = [b for b in divisors(dv) if lane_tileable(b, dv)]
+        blks = [b for b in tileable
+                if ntuples * d * d * b * 4 <= cap][-3:] or tileable[:1]
     else:
         blks = [dv]
 
@@ -204,6 +208,16 @@ def candidate_schedules(kernel: str, key: ShapeKey,
     return out
 
 
+def _rows_tileable(bm: int, d: int) -> bool:
+    """The [bm·D, ·] m2 row blocks obey the TPU's 8-row sublane tile."""
+    return (bm * d) % 8 == 0 or bm == d
+
+
+def tpu_tileable(sched: Schedule, d: int, dv: int) -> bool:
+    """Whether the TPU compiler can tile `sched`'s blocks at (D, Dv)."""
+    return _rows_tileable(sched.bm, d) and lane_tileable(sched.blk, dv)
+
+
 # ---------------------------------------------------------------------------
 # deterministic analytic cost model
 # ---------------------------------------------------------------------------
@@ -222,6 +236,8 @@ def cost_model(key: ShapeKey, sched: Schedule) -> float:
     """
     n, d, dv, g, p = key.n, key.d, key.dv, key.g, key.p
     bm, blk, c, grid = sched
+    if not tpu_tileable(sched, d, dv):
+        return math.inf
     inb = 2 if "bfloat16" in key.dtype or "float16" in key.dtype else 4
     d2 = d * d if p >= 2 else 1
     mega = MEGACORE if grid == "parallel" else 1
@@ -359,12 +375,8 @@ def measure(key: ShapeKey, sched: Schedule, *, iters: int = 5,
 
 def _trace_clean() -> bool:
     """True when no jax trace is active (safe to execute kernels)."""
-    import jax
-    fn = getattr(jax.core, "trace_state_clean", None)
-    try:
-        return bool(fn()) if fn is not None else True
-    except Exception:   # noqa: BLE001 — version drift; err on the safe side
-        return False
+    from jax._src.core import trace_state_clean
+    return trace_state_clean()
 
 
 # ---------------------------------------------------------------------------
@@ -448,7 +460,7 @@ def _entry_schedule(entry: dict, key: ShapeKey) -> Optional[Schedule]:
     except (KeyError, TypeError):
         return None
     if (key.d % s.bm or key.dv % s.blk or s.chunk_size < 1
-            or s.grid not in GRIDS):
+            or s.grid not in GRIDS or not tpu_tileable(s, key.d, key.dv)):
         return None
     return s
 

@@ -13,9 +13,11 @@ VMEM scratch (O(D^{p+1}) bytes total), and every heavy op is an MXU matmul:
 
 Layout notes (TPU):
   * degree-2 moment scratch is [D·D, blk] (m-major) so both the update
-    (T^T @ V) and the query contraction slice contiguous row blocks — no
-    reshapes of scratch, only a [C, bm, D] → [C, bm·D] collapse of the
-    last two dims of a freshly built tile.
+    (T^T @ V) and the query contraction slice contiguous row blocks. The
+    degree-2 features of a row block are built TRANSPOSED, [bm·D, rows],
+    from k̂ᵀ/q̂ᵀ held in VMEM scratch (`_outer_rows`): the block's dynamic
+    offset then falls on the sublane axis (one-row loads), which Mosaic
+    lowers, where a dynamic lane slice of a value does not.
   * the VALUE-FEATURE axis of the carry (and of v / o / the emitted
     m-moments) is tiled into nb = Dv/blk independent column blocks
     (`pick_blk`): per-block scratch is D²·blk·4 bytes, so D = Dv = 128
@@ -24,6 +26,12 @@ Layout notes (TPU):
     (QK^T, the denominator, the g-carry) and emits ITS slice of o and the
     m-moments — outputs slice cleanly because o = num/(den+eps) splits
     along Dv.
+  * the validity mask is laid out [B·Hkv, 1, N] with (1, 1, C) blocks, so
+    its block obeys the (8, 128) tiling rule (a [B·Hkv, N] layout with
+    (1, C) blocks does not).
+  * state blocks whose index is constant along the chunk axis (init state
+    in, final state out) are single-buffered: at D = Dv = 128 each m2 block
+    is 8 MB of VMEM.
   * grid = (B·Hkv, nb, N/C): head and Dv-block axes "parallel"
     (independent), chunk axis "arbitrary" (sequential — the scan carry).
   * GQA: Q arrives [B·Hkv, G, N, D]; the G query heads of a group are
@@ -43,8 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-from repro.kernels.tiling import FWD_BLK_BUDGET, pick_blk, pick_bm
+from repro.kernels.tiling import (FWD_BLK_BUDGET, VMEM_LIMIT_BYTES,
+                                  pick_blk, pick_bm)
 
 __all__ = ["fastmax_causal_pallas"]
 
@@ -56,13 +64,56 @@ def _poly(s, p):
     return out
 
 
+def _outer_rows(xt_ref, i, bm):
+    """Rows [i·bm·D, (i+1)·bm·D) of the m-major degree-2 expansion of x,
+    transposed: row a·D + b is x[:, i·bm + a] · x[:, b], shape [bm·D, R].
+    `xt_ref` holds xᵀ [D, R] in VMEM, so the dynamic index `i` only ever
+    selects sublane rows of a ref."""
+    xt = xt_ref[...]
+    return jnp.concatenate(
+        [xt * xt_ref[pl.ds(i * bm + a, 1), :] for a in range(bm)], axis=0)
+
+
+def _m_rows(i, rows):
+    """pl.ds over row block `i` of `rows` rows of an m-major moment."""
+    start = i * rows
+    if rows % 8 == 0:
+        start = pl.multiple_of(start, 8)
+    return pl.ds(start, rows)
+
+
+def _tn_dot(a, b, acc):
+    """aᵀ @ b (contract the leading dims) with `acc` accumulation."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=acc)
+
+
+def _nt_dot(a, b, acc):
+    """a @ bᵀ (contract the trailing dims) with `acc` accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=acc)
+
+
+def _state_spec(block, index_map):
+    """BlockSpec of a state block whose index is constant along the chunk
+    axis: one VMEM buffer instead of the default two."""
+    return pl.BlockSpec(block, index_map, pipeline_mode=pl.Buffered(1))
+
+
+def compiler_params(dimension_semantics):
+    """Mosaic parameters shared by the fastmax kernels."""
+    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics),
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
 def _causal_kernel(
     q_ref,   # [1, G, C, D]
     k_ref,   # [1, C, D]
     v_ref,   # [1, C, Dv]
-    w_ref,   # [1, C]       validity mask (1=real token, 0=padding)
+    w_ref,   # [1, 1, C]    validity mask (1=real token, 0=padding)
     *refs,   # [init-state inputs (has_init)] + o_ref +
     #          [state outputs (emit_state)] + 6 moment scratch buffers
+    #          + q̂ᵀ/k̂ᵀ scratch
     p: int,
     bm: int,
     denom_eps: float,
@@ -82,7 +133,7 @@ def _causal_kernel(
         # final-carry outputs, m-major m2 — the decode kernel's native layout
         (m0o, m1o, m2o, g0o, g1o, g2o) = refs[:6]
         refs = refs[6:]
-    m0_s, m1_s, m2_s, g0_s, g1_s, g2_s = refs
+    m0_s, m1_s, m2_s, g0_s, g1_s, g2_s, qt_s, kt_s = refs
     c = pl.program_id(2)
     nc = pl.num_programs(2)
     g, cs, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
@@ -111,7 +162,7 @@ def _causal_kernel(
     q = q_ref[0].astype(f32).reshape(g * cs, d)   # [GC, D]
     k = k_ref[0].astype(f32)                      # [C, D]
     v = v_ref[0].astype(f32)                      # [C, Dv]
-    w = w_ref[0].astype(f32)                      # [C]
+    w = w_ref[0, 0].astype(f32)                   # [C]
 
     # ---- inter-chunk: contract carry (strictly-previous chunks) with q ----
     num = jnp.broadcast_to(m0_s[...], (g * cs, dv)) + jnp.dot(
@@ -124,11 +175,12 @@ def _causal_kernel(
             axis=-1,
         )
 
-        def mb_step(i, acc):
-            qm = jax.lax.dynamic_slice_in_dim(q, i * bm, bm, 1)  # [GC, bm]
-            y = (qm[:, :, None] * q[:, None, :]).reshape(g * cs, bm * d)
-            z = m2_s[pl.dslice(i * bm * d, bm * d), :]      # [bm*D, Dv]
-            return acc + jnp.dot(y, z, preferred_element_type=f32)
+        qt_s[...] = q.T
+
+        def mb_step(i, acc_):
+            y = _outer_rows(qt_s, i, bm)                    # [bm*D, GC]
+            z = m2_s[_m_rows(i, bm * d), :]                 # [bm*D, Dv]
+            return acc_ + _tn_dot(y, z, f32)
 
         num = num + 0.5 * jax.lax.fori_loop(
             0, d // bm, mb_step, jnp.zeros((g * cs, dv), f32)
@@ -156,11 +208,12 @@ def _causal_kernel(
     if p >= 2:
         g2_s[...] += jnp.dot(kw.T, k, preferred_element_type=f32)
 
+        kt_s[...] = k.T
+
         def mb_up(i, _):
-            km = jax.lax.dynamic_slice_in_dim(k, i * bm, bm, 1)  # [C, bm]
-            t = (km[:, :, None] * k[:, None, :]).reshape(cs, bm * d)
-            m2_s[pl.dslice(i * bm * d, bm * d), :] += jnp.dot(
-                t.T, vw, preferred_element_type=f32
+            t = _outer_rows(kt_s, i, bm)                    # [bm*D, C]
+            m2_s[_m_rows(i, bm * d), :] += jnp.dot(
+                t, vw, preferred_element_type=f32
             )
             return 0
 
@@ -244,7 +297,7 @@ def fastmax_causal_pallas(
         w = jnp.ones((b, hkv, n), acc)
     else:
         w = jnp.broadcast_to(kv_mask.astype(acc), (b, hkv, n))
-    w = jnp.pad(w, ((0, 0), (0, 0), (0, pad))).reshape(b * hkv, nc * cs)
+    w = jnp.pad(w, ((0, 0), (0, 0), (0, pad))).reshape(b * hkv, 1, nc * cs)
 
     if bm is None:
         bm = pick_bm(d)
@@ -272,9 +325,17 @@ def fastmax_causal_pallas(
         pl.BlockSpec((1, g, cs, d), lambda h, b_, c: (h, 0, c, 0)),
         pl.BlockSpec((1, cs, d), lambda h, b_, c: (h, c, 0)),
         pl.BlockSpec((1, cs, blk), lambda h, b_, c: (h, c, b_)),
-        pl.BlockSpec((1, cs), lambda h, b_, c: (h, c)),
+        pl.BlockSpec((1, 1, cs), lambda h, b_, c: (h, 0, c)),
     ]
     operands = [qp, kp, vp, w]
+    state_specs = [
+        _state_spec((1, 1, blk), vb),
+        _state_spec((1, d, blk), vb),
+        _state_spec((1, m2_rows, blk), vb),
+        _state_spec((1, 1, 1), sm),
+        _state_spec((1, 1, d), sm),
+        _state_spec((1, d, d), sm),
+    ]
     if has_init:
         i0, i1, i2, j0, j1, j2 = init_state
         operands += [
@@ -286,25 +347,11 @@ def fastmax_causal_pallas(
             j1.astype(acc).reshape(bh, 1, d),
             j2.astype(acc).reshape(bh, d, d),
         ]
-        in_specs += [
-            pl.BlockSpec((1, 1, blk), vb),
-            pl.BlockSpec((1, d, blk), vb),
-            pl.BlockSpec((1, m2_rows, blk), vb),
-            pl.BlockSpec((1, 1, 1), sm),
-            pl.BlockSpec((1, 1, d), sm),
-            pl.BlockSpec((1, d, d), sm),
-        ]
+        in_specs += state_specs
     out_specs = [pl.BlockSpec((1, g, cs, blk), lambda h, b_, c: (h, 0, c, b_))]
     out_shape = [jax.ShapeDtypeStruct((bh, g, nc * cs, dv), out_dtype)]
     if return_state:
-        out_specs += [
-            pl.BlockSpec((1, 1, blk), vb),
-            pl.BlockSpec((1, d, blk), vb),
-            pl.BlockSpec((1, m2_rows, blk), vb),
-            pl.BlockSpec((1, 1, 1), sm),
-            pl.BlockSpec((1, 1, d), sm),
-            pl.BlockSpec((1, d, d), sm),
-        ]
+        out_specs += state_specs
         out_shape += [
             jax.ShapeDtypeStruct((bh, 1, dv), acc),
             jax.ShapeDtypeStruct((bh, d, dv), acc),
@@ -326,6 +373,8 @@ def fastmax_causal_pallas(
             pltpu.VMEM((1, 1), acc),
             pltpu.VMEM((1, d), acc),
             pltpu.VMEM((d, d), acc),
+            pltpu.VMEM((d, g * cs), acc),
+            pltpu.VMEM((d, cs), acc),
         ],
         # nb must be sequential when emitting state: every Dv-block program
         # writes the SAME g-state output block (identical values), and
@@ -333,7 +382,7 @@ def fastmax_causal_pallas(
         # undefined on megacore (two cores would DMA it concurrently).
         # Without state outputs every block writes disjoint o slices, so
         # nb follows the schedule's `grid` knob.
-        compiler_params=tpu_compiler_params(
+        compiler_params=compiler_params(
             (par, "arbitrary" if return_state else par, "arbitrary")),
         interpret=interpret,
         name=f"fastmax_causal_p{p}",

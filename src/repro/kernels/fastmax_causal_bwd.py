@@ -39,7 +39,12 @@ Every heavy op is an MXU matmul; the degree-2 tensors stream in the same
 m-major [bm·D, blk] blocks as the forward. Scratch is two moment tuples
 (carry + carry-cotangent): O(D²·blk) bytes, independent of N — the §2.5
 bound, now with zero HBM round-trips for the reconstruction AND a VMEM
-footprint that fits production 128×128 heads (blk = pick_blk ⇒ nb = 2).
+footprint that fits production 128×128 heads (blk = pick_blk = 128 ⇒
+nb = 1, with the scoped-VMEM limit raised to `VMEM_LIMIT_BYTES`). The
+degree-2 features are built transposed from q̂ᵀ/k̂ᵀ scratch, the dk columns
+of an m-row block are written as rows of dk̂ᵀ scratch, and dq accumulates
+transposed, so every dynamic index is a sublane row (see
+`fastmax_causal._outer_rows`).
 
 Validated in interpret mode against the jnp `_causal_scan_cg_bwd` oracle
 and oracle autodiff (tests/test_kernels.py) over p ∈ {1,2}, GQA group
@@ -54,8 +59,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-from repro.kernels.fastmax_causal import _poly
+from repro.kernels.fastmax_causal import (_m_rows, _nt_dot, _outer_rows,
+                                          _poly, _state_spec, _tn_dot,
+                                          compiler_params)
 from repro.kernels.tiling import BWD_BLK_BUDGET, pick_blk, pick_bm
 
 __all__ = ["fastmax_causal_bwd_pallas"]
@@ -65,7 +71,7 @@ def _causal_bwd_kernel(
     q_ref,    # [1, G, C, D]
     k_ref,    # [1, C, D]
     v_ref,    # [1, C, BLK]    this program's Dv column block
-    w_ref,    # [1, C]         validity mask (1=real token)
+    w_ref,    # [1, 1, C]      validity mask (1=real token)
     do_ref,   # [1, G, C, BLK]
     fm0_ref,  # [1, 1, BLK]    final moments (read once, at the last chunk)
     fm1_ref,  # [1, D, BLK]
@@ -76,7 +82,8 @@ def _causal_bwd_kernel(
     dq_ref,   # [1, 1, G, C, D]  per-block PARTIAL (summed by the wrapper)
     dk_ref,   # [1, 1, C, D]     per-block PARTIAL
     dv_ref,   # [1, C, BLK]      exact slice
-    *refs,    # [dstate outputs (return_dstate)] + 12 scratch buffers
+    *refs,    # [dstate outputs (return_dstate)] + 12 moment scratch
+    #           buffers + q̂ᵀ/k̂ᵀ/uᵀ/dk̂ᵀ scratch
     p: int,
     bm: int,
     denom_eps: float,
@@ -92,7 +99,7 @@ def _causal_bwd_kernel(
         refs = refs[6:]
     # scratch: carry moments + carry-cotangent moments (Dv-block columns)
     (m0_s, m1_s, m2_s, g0_s, g1_s, g2_s,
-     gm0_s, gm1_s, gm2_s, gg0_s, gg1_s, gg2_s) = refs
+     gm0_s, gm1_s, gm2_s, gg0_s, gg1_s, gg2_s, qt_s, kt_s, ut_s, dkt_s) = refs
     t = pl.program_id(2)   # reverse step: chunk = nc-1-t via the index maps
     g, cs, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     blk = v_ref.shape[2]
@@ -118,10 +125,13 @@ def _causal_bwd_kernel(
     q = q_ref[0].astype(f32).reshape(gc, d)
     k = k_ref[0].astype(f32)
     v = v_ref[0].astype(f32)
-    w = w_ref[0].astype(f32)
+    w = w_ref[0, 0].astype(f32)
     do = do_ref[0].astype(f32).reshape(gc, blk)
     kw = k * w[:, None]
     vw = v * w[:, None]
+    if p >= 2:
+        qt_s[...] = q.T
+        kt_s[...] = k.T
 
     # ---- 1. reversible carry: carry_before = carry_after − Δchunk --------
     # (op-for-op mirror of the forward fold, so the subtraction is exact;
@@ -134,10 +144,9 @@ def _causal_bwd_kernel(
         g2_s[...] -= jnp.dot(kw.T, k, preferred_element_type=f32)
 
         def mb_down(i, _):
-            km = jax.lax.dynamic_slice_in_dim(k, i * bm, bm, 1)  # [C, bm]
-            tt = (km[:, :, None] * k[:, None, :]).reshape(cs, bm * d)
-            m2_s[pl.dslice(i * bm * d, bm * d), :] -= jnp.dot(
-                tt.T, vw, preferred_element_type=f32)
+            tt = _outer_rows(kt_s, i, bm)                    # [bm*D, C]
+            m2_s[_m_rows(i, bm * d), :] -= jnp.dot(
+                tt, vw, preferred_element_type=f32)
             return 0
 
         jax.lax.fori_loop(0, d // bm, mb_down, 0)
@@ -146,16 +155,18 @@ def _causal_bwd_kernel(
     # num: this block's Dv columns only; den: full (Dv-independent)
     num = jnp.broadcast_to(m0_s[...], (gc, blk)) + jnp.dot(
         q, m1_s[...], preferred_element_type=f32)
-    den = g0_s[0, 0] + jnp.dot(q, g1_s[0], preferred_element_type=f32)
+    # den/deni/sden stay [GC, 1] columns: Mosaic cannot relayout a 1-D
+    # vector longer than one lane row into a column
+    den = g0_s[...] + _nt_dot(q, g1_s[...], f32)
     if p >= 2:
         den = den + 0.5 * jnp.sum(
-            jnp.dot(q, g2_s[...], preferred_element_type=f32) * q, axis=-1)
+            jnp.dot(q, g2_s[...], preferred_element_type=f32) * q, axis=-1,
+            keepdims=True)
 
         def mb_num(i, a):
-            qm = jax.lax.dynamic_slice_in_dim(q, i * bm, bm, 1)
-            y = (qm[:, :, None] * q[:, None, :]).reshape(gc, bm * d)
-            z = m2_s[pl.dslice(i * bm * d, bm * d), :]
-            return a + jnp.dot(y, z, preferred_element_type=f32)
+            y = _outer_rows(qt_s, i, bm)                     # [bm*D, GC]
+            z = m2_s[_m_rows(i, bm * d), :]
+            return a + _tn_dot(y, z, f32)
 
         num = num + 0.5 * jax.lax.fori_loop(
             0, d // bm, mb_num, jnp.zeros((gc, blk), f32))
@@ -166,66 +177,66 @@ def _causal_bwd_kernel(
     mask = (qpos >= kpos).astype(f32) * w[None, :]
     fs = _poly(s_qk, p) * mask
     num = num + jnp.dot(fs, v, preferred_element_type=f32)
-    den = den + jnp.sum(fs, axis=-1)
+    den = den + jnp.sum(fs, axis=-1, keepdims=True)
 
     deni = 1.0 / (den + denom_eps)
-    o = num * deni[:, None]                # this block's output columns
-    u = do * deni[:, None]                 # dL/dnum (block columns)
-    sden = -jnp.sum(o * u, axis=-1)        # block PARTIAL of dL/dden  [GC]
+    o = num * deni                         # this block's output columns
+    u = do * deni                          # dL/dnum (block columns)
+    sden = -jnp.sum(o * u, axis=-1, keepdims=True)  # block PARTIAL of dL/dden
 
     # ---- 3a. intra-chunk grads through the f(QK^T) block ------------------
     # ds decomposes additively over Dv blocks: u@v^T contracts only this
     # block's columns and sden is the block partial, so Σ_blocks ds == full
     fprime = (1.0 + s_qk) if p >= 2 else jnp.ones_like(s_qk)
     ds = (jnp.dot(u, v.T, preferred_element_type=f32)
-          + sden[:, None]) * fprime * mask
+          + sden) * fprime * mask
     dq = jnp.dot(ds, k, preferred_element_type=f32)      # [GC, D]
     dk = jnp.dot(ds.T, q, preferred_element_type=f32)    # [C, D]
     dvv = jnp.dot(fs.T, u, preferred_element_type=f32)   # [C, BLK]
 
     # ---- 3b. inter-chunk dq through the carry moments ---------------------
     dq += jnp.dot(u, m1_s[...].T, preferred_element_type=f32)
-    dq += sden[:, None] * g1_s[0][None, :]
+    dq += sden * g1_s[...]
     if p >= 2:
-        dq += sden[:, None] * jnp.dot(q, g2_s[...],
-                                      preferred_element_type=f32)
+        dq += sden * jnp.dot(q, g2_s[...], preferred_element_type=f32)
 
-        def mb_dq(i, a):
-            z = m2_s[pl.dslice(i * bm * d, bm * d), :]       # [bm*D, BLK]
-            tmp = jnp.dot(u, z.T, preferred_element_type=f32)
-            tmp = tmp.reshape(gc, bm, d)
-            blk_ = jnp.sum(tmp * q[:, None, :], axis=-1)      # [GC, bm]
-            return jax.lax.dynamic_update_slice(a, blk_, (0, i * bm))
+        # m2 is symmetric in its two feature indices (row a·D+b == row
+        # b·D+a), so Σ_b (u·m2[c·D+b]) q_b == Σ_b q_b (u·m2[b·D+c]): dqᵀ
+        # accumulates whole [D, GC] terms, one m2 row block per feature b
+        ut_s[...] = u.T
 
-        dq += jax.lax.fori_loop(0, d // bm, mb_dq,
-                                jnp.zeros((gc, d), f32))
+        # unrolled in Python: inside a fori_loop the TPU compiler rejects
+        # this matmul (internal error in its MXU transpose folding)
+        dqt = jnp.zeros((d, gc), f32)
+        for b_ in range(d):
+            dqt = dqt + qt_s[b_:b_ + 1, :] * jnp.dot(
+                m2_s[b_ * d:(b_ + 1) * d, :], ut_s[...],
+                preferred_element_type=f32)
+        dq += dqt.T
 
     # ---- 3c. dk/dv through this chunk's moment delta (uses the carry-
     # cotangent accumulated from LATER chunks — before step 4 updates it) ---
     dk += w[:, None] * jnp.dot(v, gm1_s[...].T, preferred_element_type=f32)
-    dk += w[:, None] * gg1_s[0][None, :]
+    dk += w[:, None] * gg1_s[...]
     dvv += w[:, None] * jnp.broadcast_to(gm0_s[...], (cs, blk))
     dvv += w[:, None] * jnp.dot(k, gm1_s[...], preferred_element_type=f32)
     if p >= 2:
         dk += 2.0 * w[:, None] * jnp.dot(k, gg2_s[...],
                                          preferred_element_type=f32)
 
-        def mb_dkv(i, carry):
-            dk_a, dv_a = carry
-            z = gm2_s[pl.dslice(i * bm * d, bm * d), :]      # [bm*D, BLK]
-            km = jax.lax.dynamic_slice_in_dim(k, i * bm, bm, 1)
-            tt = (km[:, :, None] * k[:, None, :]).reshape(cs, bm * d)
-            dv_a = dv_a + jnp.dot(tt, z, preferred_element_type=f32)
-            tmp = jnp.dot(vw, z.T, preferred_element_type=f32)
-            tmp = tmp.reshape(cs, bm, d)
-            blk_ = 2.0 * jnp.sum(tmp * k[:, None, :], axis=-1)  # [C, bm]
-            dk_a = jax.lax.dynamic_update_slice(dk_a, blk_, (0, i * bm))
-            return dk_a, dv_a
+        def mb_dkv(i, dv_a):
+            z = gm2_s[_m_rows(i, bm * d), :]                 # [bm*D, BLK]
+            tt = _outer_rows(kt_s, i, bm)                    # [bm*D, C]
+            dv_a = dv_a + _tn_dot(tt, z, f32)
+            tmp = _nt_dot(z, vw, f32)                        # [bm*D, C]
+            for a in range(bm):   # dk[:, i*bm + a] as a row of dkᵀ
+                dkt_s[pl.ds(i * bm + a, 1), :] = 2.0 * jnp.sum(
+                    tmp[a * d:(a + 1) * d] * kt_s[...], axis=0, keepdims=True)
+            return dv_a
 
-        dk2, dv2 = jax.lax.fori_loop(
-            0, d // bm, mb_dkv,
-            (jnp.zeros((cs, d), f32), jnp.zeros((cs, blk), f32)))
-        dk += dk2
+        dv2 = jax.lax.fori_loop(0, d // bm, mb_dkv,
+                                jnp.zeros((cs, blk), f32))
+        dk += dkt_s[...].T
         dvv += w[:, None] * dv2
 
     # ---- 4. fold this chunk's carry-cotangent for earlier chunks ----------
@@ -233,17 +244,16 @@ def _causal_bwd_kernel(
     # they feed (step 3c) stay additively decomposed too
     gm0_s[...] += jnp.sum(u, axis=0, keepdims=True)
     gm1_s[...] += jnp.dot(q.T, u, preferred_element_type=f32)
-    gg0_s[...] += jnp.sum(sden).reshape(1, 1)
-    gg1_s[...] += jnp.sum(sden[:, None] * q, axis=0, keepdims=True)
+    gg0_s[...] += jnp.sum(sden, axis=0, keepdims=True)
+    gg1_s[...] += jnp.sum(sden * q, axis=0, keepdims=True)
     if p >= 2:
-        gg2_s[...] += 0.5 * jnp.dot(q.T, q * sden[:, None],
+        gg2_s[...] += 0.5 * jnp.dot(q.T, q * sden,
                                     preferred_element_type=f32)
 
         def mb_gm2(i, _):
-            qm = jax.lax.dynamic_slice_in_dim(q, i * bm, bm, 1)
-            y = (qm[:, :, None] * q[:, None, :]).reshape(gc, bm * d)
-            gm2_s[pl.dslice(i * bm * d, bm * d), :] += 0.5 * jnp.dot(
-                y.T, u, preferred_element_type=f32)
+            y = _outer_rows(qt_s, i, bm)                     # [bm*D, GC]
+            gm2_s[_m_rows(i, bm * d), :] += 0.5 * jnp.dot(
+                y, u, preferred_element_type=f32)
             return 0
 
         jax.lax.fori_loop(0, d // bm, mb_gm2, 0)
@@ -304,11 +314,12 @@ def fastmax_causal_bwd_pallas(
     gradient the seed — i.e. every earlier shard's moment delta — receives.
 
     `blk` is the Dv carry-block width (must divide Dv); None picks the
-    largest divisor keeping BOTH degree-2 scratch tuples under
-    `BWD_BLK_BUDGET` each — nb = Dv/blk = 1 (the unblocked schedule) up to
-    64×64 heads, nb = 2 at 128×128. Feature-TP callers pass their LOCAL Dv
-    shard; the emitted dq/dk are then the shard's partials (psummed once
-    per launch by `repro.kernels.sharded`). `bm` (m-major row block, must
+    largest lane-tileable divisor keeping BOTH degree-2 scratch tuples
+    under `BWD_BLK_BUDGET` each (the smallest tileable one when none fits)
+    — nb = Dv/blk = 1 (the unblocked schedule) through 128×128 heads.
+    Feature-TP callers pass their LOCAL Dv shard; the emitted dq/dk are
+    then the shard's partials (psummed once per launch by
+    `repro.kernels.sharded`). `bm` (m-major row block, must
     divide D) and `grid` ("parallel"|"arbitrary" for the independent grid
     axes) are the autotuner's remaining schedule knobs; None keeps the
     untuned defaults.
@@ -333,7 +344,7 @@ def fastmax_causal_bwd_pallas(
         bh, nc * cs, dv)
     dop = jnp.pad(do, ((0, 0), (0, 0), (0, pad), (0, 0))).reshape(
         b, hkv, g, nc * cs, dv).reshape(bh, g, nc * cs, dv)
-    w = jnp.pad(jnp.ones((bh, n), acc), ((0, 0), (0, pad)))
+    w = jnp.pad(jnp.ones((bh, 1, n), acc), ((0, 0), (0, 0), (0, pad)))
 
     m0, m1, m2, g0, g1, g2 = state
     m2_rows = d * d if p >= 2 else 1
@@ -359,6 +370,7 @@ def fastmax_causal_bwd_pallas(
         raise ValueError(f"grid={grid!r}; expected 'parallel'|'arbitrary'")
     par = "parallel" if grid == "parallel" else "arbitrary"
     nb = dv // blk
+    gc = g * cs
     kernel = functools.partial(_causal_bwd_kernel, p=p, bm=bm,
                                denom_eps=denom_eps, acc=acc,
                                return_dstate=return_dstate)
@@ -389,12 +401,12 @@ def fastmax_causal_bwd_pallas(
         # are reduced below — the same partial/slice split as dq/dk vs dv
         nbm = lambda h, b_, t: (h, b_, 0, 0)         # noqa: E731
         out_specs += [
-            pl.BlockSpec((1, 1, blk), vb),
-            pl.BlockSpec((1, d, blk), vb),
-            pl.BlockSpec((1, m2_rows, blk), vb),
-            pl.BlockSpec((1, 1, 1, 1), nbm),
-            pl.BlockSpec((1, 1, 1, d), nbm),
-            pl.BlockSpec((1, 1, d, d), nbm),
+            _state_spec((1, 1, blk), vb),
+            _state_spec((1, d, blk), vb),
+            _state_spec((1, m2_rows, blk), vb),
+            _state_spec((1, 1, 1, 1), nbm),
+            _state_spec((1, 1, 1, d), nbm),
+            _state_spec((1, 1, d, d), nbm),
         ]
         out_shape += [
             jax.ShapeDtypeStruct((bh, 1, dv), acc),
@@ -411,14 +423,14 @@ def fastmax_causal_bwd_pallas(
             pl.BlockSpec((1, g, cs, d), revq),
             pl.BlockSpec((1, cs, d), rev),
             pl.BlockSpec((1, cs, blk), revb),
-            pl.BlockSpec((1, cs), lambda h, b_, t: (h, nc - 1 - t)),
+            pl.BlockSpec((1, 1, cs), lambda h, b_, t: (h, 0, nc - 1 - t)),
             pl.BlockSpec((1, g, cs, blk), revqb),
-            pl.BlockSpec((1, 1, blk), vb),
-            pl.BlockSpec((1, d, blk), vb),
-            pl.BlockSpec((1, m2_rows, blk), vb),
-            pl.BlockSpec((1, 1, 1), sm),
-            pl.BlockSpec((1, 1, d), sm),
-            pl.BlockSpec((1, d, d), sm),
+            _state_spec((1, 1, blk), vb),
+            _state_spec((1, d, blk), vb),
+            _state_spec((1, m2_rows, blk), vb),
+            _state_spec((1, 1, 1), sm),
+            _state_spec((1, 1, d), sm),
+            _state_spec((1, d, d), sm),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -435,8 +447,12 @@ def fastmax_causal_bwd_pallas(
             pltpu.VMEM((1, 1), acc),
             pltpu.VMEM((1, d), acc),
             pltpu.VMEM((d, d), acc),
+            pltpu.VMEM((d, gc), acc),
+            pltpu.VMEM((d, cs), acc),
+            pltpu.VMEM((blk, gc), acc),
+            pltpu.VMEM((d, cs), acc),
         ],
-        compiler_params=tpu_compiler_params((par, par, "arbitrary")),
+        compiler_params=compiler_params((par, par, "arbitrary")),
         interpret=interpret,
         name=f"fastmax_causal_bwd_p{p}",
     )(qp, kp, vp, w, dop, fm0, fm1, fm2, fg0, fg1, fg2)

@@ -12,6 +12,11 @@ Decode is memory-bound on streaming m2 (D²·Dv·4 bytes ≈ 8 MB/head for
 D=Dv=128); fusing update+combine halves HBM traffic vs two separate ops and
 is why this kernel exists. HBM state buffers are reused in place via
 input_output_aliases.
+
+The degree-2 rows of m-block `mb` are built from q̂ᵀ [D, G] and k̂ as a
+[D, 1] column, both passed in that layout, so the block's dynamic offset
+selects sublane rows of a ref (`fastmax_causal._outer_rows`) — Mosaic does
+not lower a dynamic lane slice of a value.
 """
 from __future__ import annotations
 
@@ -22,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels.fastmax_causal import _outer_rows, _tn_dot, compiler_params
 from repro.kernels.tiling import pick_bm
 
 __all__ = ["fastmax_decode_pallas"]
@@ -30,6 +35,7 @@ __all__ = ["fastmax_decode_pallas"]
 
 def _decode_kernel(q_ref, k_ref, v_ref,
                    m0_ref, m1_ref, m2_ref, g0_ref, g1_ref, g2_ref,
+                   qt_ref, kc_ref,
                    o_ref, m0o, m1o, m2o, g0o, g1o, g2o,
                    acc_s, den_s, *, p, bm, nmb, denom_eps, acc):
     mb = pl.program_id(1)
@@ -61,13 +67,11 @@ def _decode_kernel(q_ref, k_ref, v_ref,
         den_s[...] = den[:, None]
 
     if p >= 2:
-        km = jax.lax.dynamic_slice_in_dim(k, mb * bm, bm, 0)  # [bm]
-        t = (km[:, None] * k[None, :]).reshape(bm * d)       # [bm*D]
-        m2 = m2_ref[0] + t[:, None] * v[None, :]             # [bm*D, Dv]
+        t = _outer_rows(kc_ref, mb, bm)                     # [bm*D, 1]
+        m2 = m2_ref[0] + t * v[None, :]                      # [bm*D, Dv]
         m2o[0] = m2
-        qm = jax.lax.dynamic_slice_in_dim(q, mb * bm, bm, 1)
-        y = (qm[:, :, None] * q[:, None, :]).reshape(g, bm * d)
-        acc_s[...] += 0.5 * jnp.dot(y, m2, preferred_element_type=acc)
+        y = _outer_rows(qt_ref, mb, bm)                     # [bm*D, G]
+        acc_s[...] += 0.5 * _tn_dot(y, m2, acc)
 
     @pl.when(mb == nmb - 1)
     def _emit():
@@ -102,7 +106,10 @@ def fastmax_decode_pallas(
 
     acc = jnp.promote_types(q.dtype, jnp.float32)
     qr = q.reshape(b, hkv, g, d).reshape(bh, g, d)
+    # 2-D [BH·D, ·] so each head's block is a plain [D, ·] ref
+    qt = jnp.swapaxes(qr, 1, 2).reshape(bh * d, g).astype(acc)
     kr = k.reshape(bh, 1, d)
+    kc = kr.reshape(bh * d, 1).astype(acc)
     vr = v.reshape(bh, 1, dv)
     m0r = m0.reshape(bh, 1, dv).astype(acc)
     m1r = m1.reshape(bh, d, dv).astype(acc)
@@ -142,6 +149,8 @@ def fastmax_decode_pallas(
             pl.BlockSpec((1, 1, 1), sm),
             pl.BlockSpec((1, 1, d), sm),
             pl.BlockSpec((1, d, d), sm),
+            pl.BlockSpec((d, g), lambda h, mb: (h, 0)),
+            pl.BlockSpec((d, 1), lambda h, mb: (h, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, g, dv), lambda h, mb: (h, 0, 0)),
@@ -168,10 +177,10 @@ def fastmax_decode_pallas(
         input_output_aliases={3: 1, 4: 2, 5: 3, 6: 4, 7: 5, 8: 6},
         # the head axis follows the schedule's `grid` knob; the m-block
         # axis is the sequential m2 stream (carries acc/den scratch)
-        compiler_params=tpu_compiler_params((grid, "arbitrary")),
+        compiler_params=compiler_params((grid, "arbitrary")),
         interpret=interpret,
         name=f"fastmax_decode_p{p}",
-    )(qr, kr, vr, m0r, m1r, m2r, g0r, g1r, g2r)
+    )(qr, kr, vr, m0r, m1r, m2r, g0r, g1r, g2r, qt, kc)
 
     o, m0n, m1n, m2n, g0n, g1n, g2n = outs
     o = o.reshape(b, hq, 1, dv)

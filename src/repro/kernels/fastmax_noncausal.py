@@ -26,22 +26,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-from repro.kernels.fastmax_causal import _poly
+from repro.kernels.fastmax_causal import (_outer_rows, _tn_dot,
+                                          compiler_params)
 from repro.kernels.tiling import pick_bm
 
 __all__ = ["fastmax_noncausal_pallas"]
 
 
 def _moment_kernel(k_ref, v_ref, w_ref,
-                   m0_ref, m1_ref, m2_ref, g0_ref, g1_ref, g2_ref,
+                   m0_ref, m1_ref, m2_ref, g0_ref, g1_ref, g2_ref, kt_s,
                    *, p, bm, acc):
     mb, c = pl.program_id(1), pl.program_id(2)
-    cs, d = k_ref.shape[1], k_ref.shape[2]
 
     k = k_ref[0].astype(acc)
     v = v_ref[0].astype(acc)
-    w = w_ref[0].astype(acc)
+    w = w_ref[0, 0].astype(acc)
     kw = k * w[:, None]
     vw = v * w[:, None]
 
@@ -68,13 +67,14 @@ def _moment_kernel(k_ref, v_ref, w_ref,
         def _init_m2():
             m2_ref[...] = jnp.zeros_like(m2_ref)
 
-        km = jax.lax.dynamic_slice_in_dim(k, mb * bm, bm, 1)  # [C, bm]
-        t = (km[:, :, None] * k[:, None, :]).reshape(cs, bm * d)
-        m2_ref[0] += jnp.dot(t.T, vw, preferred_element_type=acc)
+        kt_s[...] = k.T
+        t = _outer_rows(kt_s, mb, bm)                       # [bm*D, C]
+        m2_ref[0] += jnp.dot(t, vw, preferred_element_type=acc)
 
 
 def _combine_kernel(q_ref, m0_ref, m1_ref, m2_ref, g0_ref, g1_ref, g2_ref,
-                    o_ref, acc_s, den_s, *, p, bm, nmb, denom_eps, acc):
+                    o_ref, acc_s, den_s, qt_s, *, p, bm, nmb, denom_eps,
+                    acc):
     mb = pl.program_id(2)
     g, cq, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     dv = m1_ref.shape[2]
@@ -94,10 +94,9 @@ def _combine_kernel(q_ref, m0_ref, m1_ref, m2_ref, g0_ref, g1_ref, g2_ref,
         den_s[...] = den[:, None]
 
     if p >= 2:
-        qm = jax.lax.dynamic_slice_in_dim(q, mb * bm, bm, 1)
-        y = (qm[:, :, None] * q[:, None, :]).reshape(g * cq, bm * d)
-        acc_s[...] += 0.5 * jnp.dot(y, m2_ref[0],
-                                    preferred_element_type=acc)
+        qt_s[...] = q.T
+        y = _outer_rows(qt_s, mb, bm)                       # [bm*D, GC]
+        acc_s[...] += 0.5 * _tn_dot(y, m2_ref[0], acc)
 
     @pl.when(mb == nmb - 1)
     def _emit():
@@ -141,7 +140,7 @@ def fastmax_noncausal_pallas(
         b * hkv, nkc * cs, d)
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, padk), (0, 0))).reshape(
         b * hkv, nkc * cs, dv)
-    w = jnp.pad(jnp.ones((b * hkv, m), acc), ((0, 0), (0, padk)))
+    w = jnp.pad(jnp.ones((b * hkv, 1, m), acc), ((0, 0), (0, 0), (0, padk)))
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, padq), (0, 0))).reshape(
         b, hkv, g, nqc * cq, d).reshape(b * hkv, g, nqc * cq, d)
 
@@ -163,7 +162,7 @@ def fastmax_noncausal_pallas(
         in_specs=[
             pl.BlockSpec((1, cs, d), lambda h, mb, c: (h, c, 0)),
             pl.BlockSpec((1, cs, dv), lambda h, mb, c: (h, c, 0)),
-            pl.BlockSpec((1, cs), lambda h, mb, c: (h, c)),
+            pl.BlockSpec((1, 1, cs), lambda h, mb, c: (h, 0, c)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, dv), lambda h, mb, c: (h, 0, 0)),
@@ -181,8 +180,8 @@ def fastmax_noncausal_pallas(
             jax.ShapeDtypeStruct((b * hkv, 1, d), acc),
             jax.ShapeDtypeStruct((b * hkv, d, d), acc),
         ],
-        compiler_params=tpu_compiler_params(
-            (grid, "arbitrary", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((d, cs), acc)],
+        compiler_params=compiler_params((grid, "arbitrary", "arbitrary")),
         interpret=interpret,
         name=f"fastmax_moments_p{p}",
     )(kp, vp, w)
@@ -206,8 +205,9 @@ def fastmax_noncausal_pallas(
         scratch_shapes=[
             pltpu.VMEM((g * cq, dv), acc),
             pltpu.VMEM((g * cq, 1), acc),
+            pltpu.VMEM((d, g * cq), acc),
         ],
-        compiler_params=tpu_compiler_params((grid, grid, "arbitrary")),
+        compiler_params=compiler_params((grid, grid, "arbitrary")),
         interpret=interpret,
         name=f"fastmax_combine_p{p}",
     )(qp, m0, m1, m2, g0, g1, g2)

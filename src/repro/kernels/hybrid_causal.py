@@ -33,28 +33,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels.fastmax_causal import (_m_rows, _outer_rows, _poly,
+                                          _state_spec, _tn_dot,
+                                          compiler_params)
 from repro.kernels.tiling import FWD_BLK_BUDGET, pick_blk, pick_bm
 
 __all__ = ["hybrid_causal_pallas"]
-
-
-def _poly(s, p):
-    out = 1.0 + s
-    if p >= 2:
-        out = out + 0.5 * s * s
-    return out
 
 
 def _hybrid_kernel(
     q_ref,    # [1, G, C, D]
     k_ref,    # [1, C, D]
     v_ref,    # [1, C, Dv-block]
-    w_ref,    # [1, C]       validity mask (1=real token, 0=padding)
+    w_ref,    # [1, 1, C]    validity mask (1=real token, 0=padding)
     kp_ref,   # [1, C, D]    previous chunk's keys   (block c-1; junk at c=0)
     vp_ref,   # [1, C, Dv-block] previous chunk's values
-    wp_ref,   # [1, C]       previous chunk's validity
+    wp_ref,   # [1, 1, C]    previous chunk's validity
     *refs,    # o_ref + [state outputs (emit_state)] + 6 moment scratch
+    #           + q̂ᵀ/k̂ᵀ scratch
     p: int,
     bm: int,
     w_eff: int,
@@ -67,7 +63,7 @@ def _hybrid_kernel(
     if emit_state:
         (m0o, m1o, m2o, g0o, g1o, g2o) = refs[:6]
         refs = refs[6:]
-    m0_s, m1_s, m2_s, g0_s, g1_s, g2_s = refs
+    m0_s, m1_s, m2_s, g0_s, g1_s, g2_s, qt_s, kt_s = refs
     c = pl.program_id(2)
     nc = pl.num_programs(2)
     g, cs, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
@@ -87,7 +83,7 @@ def _hybrid_kernel(
     q = q_ref[0].astype(f32).reshape(g * cs, d)   # [GC, D]
     k = k_ref[0].astype(f32)                      # [C, D]
     v = v_ref[0].astype(f32)                      # [C, Dv]
-    w = w_ref[0].astype(f32)                      # [C]
+    w = w_ref[0, 0].astype(f32)                   # [C]
 
     # ---- far field: contract carry (strictly-previous chunks) with q ----
     num = jnp.broadcast_to(m0_s[...], (g * cs, dv)) + jnp.dot(
@@ -100,11 +96,12 @@ def _hybrid_kernel(
             axis=-1,
         )
 
+        qt_s[...] = q.T
+
         def mb_step(i, acc_):
-            qm = jax.lax.dynamic_slice_in_dim(q, i * bm, bm, 1)  # [GC, bm]
-            y = (qm[:, :, None] * q[:, None, :]).reshape(g * cs, bm * d)
-            z = m2_s[pl.dslice(i * bm * d, bm * d), :]      # [bm*D, Dv]
-            return acc_ + jnp.dot(y, z, preferred_element_type=f32)
+            y = _outer_rows(qt_s, i, bm)                    # [bm*D, GC]
+            z = m2_s[_m_rows(i, bm * d), :]                 # [bm*D, Dv]
+            return acc_ + _tn_dot(y, z, f32)
 
         num = num + 0.5 * jax.lax.fori_loop(
             0, d // bm, mb_step, jnp.zeros((g * cs, dv), f32)
@@ -128,7 +125,7 @@ def _hybrid_kernel(
         # previous chunk's keys: distance = qpos + C - kpos, gated at c==0
         kprev = kp_ref[0].astype(f32)
         vprev = vp_ref[0].astype(f32)
-        wprev = wp_ref[0].astype(f32) * jnp.where(c > 0, 1.0, 0.0)
+        wprev = wp_ref[0, 0].astype(f32) * jnp.where(c > 0, 1.0, 0.0)
         sp = jnp.dot(q, kprev.T, preferred_element_type=f32)
         pband = (qpos + cs - kpos) < w_eff
         corr_p = jnp.where(pband, jnp.exp(sp) - _poly(sp, p), 0.0)
@@ -149,11 +146,12 @@ def _hybrid_kernel(
     if p >= 2:
         g2_s[...] += jnp.dot(kw.T, k, preferred_element_type=f32)
 
+        kt_s[...] = k.T
+
         def mb_up(i, _):
-            km = jax.lax.dynamic_slice_in_dim(k, i * bm, bm, 1)  # [C, bm]
-            t = (km[:, :, None] * k[:, None, :]).reshape(cs, bm * d)
-            m2_s[pl.dslice(i * bm * d, bm * d), :] += jnp.dot(
-                t.T, vw, preferred_element_type=f32
+            t = _outer_rows(kt_s, i, bm)                    # [bm*D, C]
+            m2_s[_m_rows(i, bm * d), :] += jnp.dot(
+                t, vw, preferred_element_type=f32
             )
             return 0
 
@@ -233,7 +231,7 @@ def hybrid_causal_pallas(
         w = jnp.ones((b, hkv, n), acc)
     else:
         w = jnp.broadcast_to(kv_mask.astype(acc), (b, hkv, n))
-    w = jnp.pad(w, ((0, 0), (0, 0), (0, pad))).reshape(b * hkv, nc * cs)
+    w = jnp.pad(w, ((0, 0), (0, 0), (0, pad))).reshape(b * hkv, 1, nc * cs)
 
     if bm is None:
         bm = pick_bm(d)
@@ -260,27 +258,27 @@ def hybrid_causal_pallas(
     # kernel nulls the c == 0 contribution via the validity gate)
     pc = lambda h, b_, c: (h, jnp.maximum(c - 1, 0), 0)   # noqa: E731
     pv = lambda h, b_, c: (h, jnp.maximum(c - 1, 0), b_)  # noqa: E731
-    pw = lambda h, b_, c: (h, jnp.maximum(c - 1, 0))      # noqa: E731
+    pw = lambda h, b_, c: (h, 0, jnp.maximum(c - 1, 0))   # noqa: E731
     in_specs = [
         pl.BlockSpec((1, g, cs, d), lambda h, b_, c: (h, 0, c, 0)),
         pl.BlockSpec((1, cs, d), lambda h, b_, c: (h, c, 0)),
         pl.BlockSpec((1, cs, blk), lambda h, b_, c: (h, c, b_)),
-        pl.BlockSpec((1, cs), lambda h, b_, c: (h, c)),
+        pl.BlockSpec((1, 1, cs), lambda h, b_, c: (h, 0, c)),
         pl.BlockSpec((1, cs, d), pc),
         pl.BlockSpec((1, cs, blk), pv),
-        pl.BlockSpec((1, cs), pw),
+        pl.BlockSpec((1, 1, cs), pw),
     ]
     operands = [qp, kp, vp, w, kp, vp, w]
     out_specs = [pl.BlockSpec((1, g, cs, blk), lambda h, b_, c: (h, 0, c, b_))]
     out_shape = [jax.ShapeDtypeStruct((bh, g, nc * cs, dv), out_dtype)]
     if return_state:
         out_specs += [
-            pl.BlockSpec((1, 1, blk), vb),
-            pl.BlockSpec((1, d, blk), vb),
-            pl.BlockSpec((1, m2_rows, blk), vb),
-            pl.BlockSpec((1, 1, 1), sm),
-            pl.BlockSpec((1, 1, d), sm),
-            pl.BlockSpec((1, d, d), sm),
+            _state_spec((1, 1, blk), vb),
+            _state_spec((1, d, blk), vb),
+            _state_spec((1, m2_rows, blk), vb),
+            _state_spec((1, 1, 1), sm),
+            _state_spec((1, 1, d), sm),
+            _state_spec((1, d, d), sm),
         ]
         out_shape += [
             jax.ShapeDtypeStruct((bh, 1, dv), acc),
@@ -303,10 +301,12 @@ def hybrid_causal_pallas(
             pltpu.VMEM((1, 1), acc),
             pltpu.VMEM((1, d), acc),
             pltpu.VMEM((d, d), acc),
+            pltpu.VMEM((d, g * cs), acc),
+            pltpu.VMEM((d, cs), acc),
         ],
         # nb sequential when emitting state, as in fastmax_causal (the
         # g-state output block is shared across Dv-block programs)
-        compiler_params=tpu_compiler_params(
+        compiler_params=compiler_params(
             (par, "arbitrary" if return_state else par, "arbitrary")),
         interpret=interpret,
         name=f"hybrid_causal_p{p}_w{w_eff}",

@@ -63,7 +63,7 @@ import os
 from typing import NamedTuple, Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["ShardPlan", "nontrivial_mesh", "plan_kernel_sharding",
@@ -295,7 +295,7 @@ def _seq_fwd_launch(q, k, v, p, chunk_size, denom_eps, plan, schedule):
         body, mesh=plan.mesh,
         in_specs=(shard4, shard4, shard4),
         out_specs=(shard4, _seq_state_specs(ba)),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -367,7 +367,7 @@ def _st_bwd(p, chunk_size, denom_eps, plan, schedule, res, do):
         body, mesh=plan.mesh,
         in_specs=(shard4, shard4, shard4, shard4, *sspecs),
         out_specs=(shard4, shard4, shard4),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, do, *state)
 
 
@@ -406,7 +406,7 @@ def fastmax_sharded(q, k, v, *, p: int, causal: bool, chunk_size: int,
             body, mesh=plan.mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec),
             out_specs=P(ba, h, None, None),
-            check_rep=False,
+            check_vma=False,
         )(q, k, v)
     if plan.mode == "seq":
         if not causal:
@@ -439,7 +439,7 @@ def fastmax_sharded(q, k, v, *, p: int, causal: bool, chunk_size: int,
             nc_body, mesh=plan.mesh,
             in_specs=(rep4, rep4, P(ba, None, None, f)),
             out_specs=P(ba, None, None, f),
-            check_rep=False,
+            check_vma=False,
         )(q, k, v)
     return _feature_trainable(q, k, v, p, chunk_size, denom_eps, plan,
                               schedule)
@@ -468,7 +468,7 @@ def _feature_fwd_launch(q, k, v, p, chunk_size, denom_eps, plan, schedule):
         body, mesh=plan.mesh,
         in_specs=(rep4, rep4, P(ba, None, None, f)),
         out_specs=(P(ba, None, None, f), _moment_specs(plan)),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -491,7 +491,7 @@ def _feature_trainable(q, k, v, p, chunk_size, denom_eps, plan, schedule):
         body, mesh=plan.mesh,
         in_specs=(rep4, rep4, P(ba, None, None, f)),
         out_specs=P(ba, None, None, f),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -537,7 +537,7 @@ def _ft_bwd(p, chunk_size, denom_eps, plan, schedule, res, do):
         in_specs=(rep4, rep4, P(ba, None, None, f), P(ba, None, None, f),
                   *mspecs),
         out_specs=(rep4, rep4, P(ba, None, None, f)),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, do, *state)
 
 
@@ -586,7 +586,7 @@ def _hybrid_feature_fwd_launch(q, k, v, p, window, chunk_size, denom_eps,
         body, mesh=plan.mesh,
         in_specs=(rep4, rep4, P(ba, None, None, f)),
         out_specs=(P(ba, None, None, f), _moment_specs(plan)),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -609,7 +609,7 @@ def _hybrid_feature_trainable(q, k, v, p, window, chunk_size, denom_eps,
         body, mesh=plan.mesh,
         in_specs=(rep4, rep4, P(ba, None, None, f)),
         out_specs=P(ba, None, None, f),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -657,7 +657,7 @@ def _hft_bwd(p, window, chunk_size, denom_eps, plan, schedule, res, do):
         in_specs=(rep4, rep4, P(ba, None, None, f), P(ba, None, None, f),
                   *mspecs),
         out_specs=(rep4, rep4, P(ba, None, None, f)),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, do, *state)
 
 
@@ -691,7 +691,7 @@ def hybrid_sharded(q, k, v, *, p: int, window: int, chunk_size: int,
             body, mesh=plan.mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec),
             out_specs=P(ba, h, None, None),
-            check_rep=False,
+            check_vma=False,
         )(q, k, v)
     if plan.mode != "feature":
         raise ValueError(
@@ -738,7 +738,7 @@ def fastmax_prefill_sharded(q, k, v, *, p: int, chunk_size: int,
         body, mesh=plan.mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(ba, h, None, f), _moment_specs(plan)),
-        check_rep=False,
+        check_vma=False,
     )(*args)
 
 
@@ -768,5 +768,5 @@ def fastmax_decode_sharded(q, k, v, state, *, p: int, denom_eps: float,
                   P(ba, h, None, f),      # v
                   *mspecs),
         out_specs=(P(ba, h, None, f), mspecs),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, *tuple(state))

@@ -11,19 +11,21 @@ Two independent blockings of the degree-2 moment `m2 [D·D, Dv]` (m-major):
   intermediate that the naive einsum would blow up to [..., N, D, Dv]).
 
 * `pick_blk` — the COLUMN (value-feature, Dv) carry block. The causal
-  forward/backward kernels hold the RUNNING moment carry in VMEM scratch;
-  at D = Dv = 128 a full degree-2 tuple is D²·Dv·4 = 8 MB, and the fused
-  backward needs TWO (carry + carry-cotangent) — past the ~16 MB/core
-  VMEM wall. Both kernels therefore tile the Dv axis of the carry into
-  `nb = Dv/blk` independent column blocks (a grid axis): per-block scratch
-  is D²·blk·4 bytes, the chunk forward is recomputed once per block from
-  the reversible carry, and every emitted quantity either slices (o, dv,
-  the m-moments) or sums (dq, dk — the contractions over Dv are linear in
-  the per-block cotangents) across blocks. blk is the largest divisor of
-  Dv with D²·blk at most the budget: 2M f32 words (8 MB) for the forward's
-  single tuple, 1M (4 MB each, 8 MB for the pair) for the backward — so
-  128×128 heads train with nb_fwd = 1, nb_bwd = 2, and small heads keep
-  nb = 1 (the unblocked schedule, bit-identical to before).
+  forward/backward kernels hold the RUNNING moment carry in VMEM scratch
+  and can tile its Dv axis into `nb = Dv/blk` independent column blocks (a
+  grid axis): per-block scratch is D²·blk·4 bytes, the chunk forward is
+  recomputed once per block from the reversible carry, and every emitted
+  quantity either slices (o, dv, the m-moments) or sums (dq, dk — the
+  contractions over Dv are linear in the per-block cotangents) across
+  blocks. A block is the minor (lane) dim of its v/o/moment tiles, so the
+  TPU can only tile widths that equal Dv or are multiples of 128
+  (`lane_tileable`). blk is the largest tileable divisor of Dv with D²·blk
+  at most the budget — 2M f32 words (8 MB) for the forward's single tuple,
+  1M for each tuple of the backward's carry + cotangent pair — or, when
+  none fits, the smallest tileable one. At D = Dv = 128 that is blk = 128
+  (nb = 1) for both: the backward's two 8 MB tuples exceed the compiler's
+  DEFAULT scoped-VMEM limit (16 MiB), not the chip's VMEM (128 MiB per
+  v5e core), so the carry kernels raise the limit to `VMEM_LIMIT_BYTES`.
 
 Both pickers are the UNTUNED defaults: the schedule autotuner
 (`repro.kernels.autotune`) sweeps bm/blk (among other knobs) per shape and
@@ -35,14 +37,23 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["pick_bm", "pick_blk", "divisors", "KERNEL_BM_BUDGET",
-           "SCAN_BM_BUDGET", "FWD_BLK_BUDGET", "BWD_BLK_BUDGET"]
+__all__ = ["pick_bm", "pick_blk", "divisors", "lane_tileable",
+           "KERNEL_BM_BUDGET", "SCAN_BM_BUDGET", "FWD_BLK_BUDGET",
+           "BWD_BLK_BUDGET", "VMEM_LIMIT_BYTES"]
 
 KERNEL_BM_BUDGET = 512   # Pallas VMEM tiles
 SCAN_BM_BUDGET = 2048    # jnp chunked-scan intermediates
 
 FWD_BLK_BUDGET = 2 << 20   # f32 words per degree-2 carry tuple (1 tuple)
 BWD_BLK_BUDGET = 1 << 20   # f32 words per tuple (carry + cotangent pair)
+
+# Scoped-VMEM limit of the kernels. The compiler's default is 16 MiB; a
+# v5e core has 128 MiB. The 128×128 backward holds two 8 MiB scratch tuples
+# plus single-buffered 8 MiB state blocks (~40 MiB in all), so the kernels
+# ask for 96 MiB and leave the rest to Mosaic's internal scratch.
+VMEM_LIMIT_BYTES = 96 << 20
+
+LANES = 128   # TPU vreg lane width: the minor dim of a block tiles by this
 
 
 @functools.lru_cache(maxsize=None)
@@ -78,9 +89,16 @@ def pick_bm(d: int, budget: int = KERNEL_BM_BUDGET) -> int:
     return best
 
 
+def lane_tileable(width: int, full: int) -> bool:
+    """Whether a block `width` wide along a minor dim of size `full` obeys
+    the TPU's (8, 128) tiling rule: the whole dim, or a multiple of 128."""
+    return width == full or width % LANES == 0
+
+
 @functools.lru_cache(maxsize=None)
 def pick_blk(d: int, dv: int, budget: int = FWD_BLK_BUDGET) -> int:
-    """Largest divisor of `dv` with d*d*blk <= budget (always >= 1).
+    """Largest lane-tileable divisor of `dv` with d*d*blk <= budget; the
+    smallest tileable one when none fits (dv itself below 128 lanes).
 
     The Dv carry-block of the causal kernels: one degree-2 scratch tuple
     is d*d*blk f32 words per grid program. blk == dv means nb == 1 — the
@@ -89,8 +107,6 @@ def pick_blk(d: int, dv: int, budget: int = FWD_BLK_BUDGET) -> int:
     _check_budget(budget)
     if not isinstance(d, int) or d < 1:
         raise ValueError(f"d must be a positive int, got {d!r}")
-    best = 1
-    for blk in divisors(dv):
-        if d * d * blk <= budget:
-            best = blk
-    return best
+    tileable = [blk for blk in divisors(dv) if lane_tileable(blk, dv)]
+    fits = [blk for blk in tileable if d * d * blk <= budget]
+    return fits[-1] if fits else tileable[0]

@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without real hardware:
   * builds the production mesh (16x16 single pod / 2x16x16 multi-pod) on 512
-    placeholder host devices (XLA_FLAGS above, set BEFORE any jax import),
+    placeholder host devices (`force_host_devices`, called by `main` before
+    the first device query, so importing this module changes nothing),
   * lowers train_step / prefill_step / serve_step against ShapeDtypeStruct
     inputs (zero allocation) with the full DP/FSDP/TP/EP sharding rules,
   * compiles, prints memory_analysis() (proves the per-device footprint) and
@@ -20,6 +18,7 @@ Usage:
 import argparse
 import contextlib
 import json
+import os
 import sys
 import tempfile
 import time
@@ -244,8 +243,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):   # older JAX returns [dict] per device program
-        cost = cost[0] if cost else {}
     hlo = analyze_hlo(compiled.as_text())
 
     # --- roofline terms (see EXPERIMENTS.md §Roofline) ---------------------
@@ -329,7 +326,14 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     return out
 
 
+def force_host_devices(n: int = 512) -> None:
+    """Give the CPU backend `n` placeholder devices for the production
+    meshes. Takes effect only before JAX's first device query."""
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+
+
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

@@ -11,13 +11,8 @@ __all__ = ["make_production_mesh", "make_test_mesh"]
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist on
-    # newer JAX; older releases treat every axis as Auto already.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, cp: int = 1):

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf probe: compile one dry-run cell and print the flops breakdown by
 op_name (+ roofline terms). The 'profiler' for the §Perf loop.
 
@@ -13,6 +10,7 @@ from repro.launch.hlo_analysis import flops_breakdown
 
 
 def main():
+    dr.force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

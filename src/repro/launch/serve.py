@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import init_decode_state, init_model
 
@@ -135,6 +136,7 @@ def main(argv=None):
                     help="seconds from submit to completion before the "
                          "request is timed out")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     import dataclasses
 

@@ -21,7 +21,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
-import time
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,10 +32,17 @@ from repro.ckpt import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLM, make_batch_iterator
 from repro.ft import PreemptionHandler, StragglerMonitor
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import make_train_step, pick_optimizer
 from repro.models import init_model
 from repro.models.param import count_params
 from repro.sharding import batch_spec, param_shardings
+
+
+class TrainResult(NamedTuple):
+    params: dict
+    losses: list                  # per-step loss, in step order
+    mesh: Optional[jax.sharding.Mesh]   # the (data, seq) mesh under --cp
 
 
 def build(args):
@@ -44,21 +51,23 @@ def build(args):
     over = {}
     if args.attn:
         over["attn"] = AttentionSpec.parse(args.attn)
+    if args.layers:
+        over["n_layers"] = args.layers
     if over:
         cfg = dataclasses.replace(cfg, **over)
     return cfg
 
 
-def _cp_mesh_context(args):
-    """Context manager activating a (data, seq) mesh when --cp > 1.
+def _cp_mesh(args):
+    """The (data, seq) mesh for --cp > 1, else None.
 
     Under the active mesh, `attention()` plans seq mode
     (`repro.kernels.sharded`): each device scans its sequence shard with
     the Pallas kernels and exchanges one constant-size moment carry per
-    boundary (forward prefix / backward suffix). --cp 1 is a no-op.
+    boundary (forward prefix / backward suffix).
     """
     if args.cp <= 1:
-        return contextlib.nullcontext()
+        return None
     from repro.launch.mesh import make_test_mesh
 
     n_dev = len(jax.devices())
@@ -71,9 +80,8 @@ def _cp_mesh_context(args):
     mesh = make_test_mesh(shape=(n_dev // args.cp, args.cp),
                           axes=("data", "seq"))
     print(f"context parallelism: cp={args.cp} "
-          f"mesh=(data={n_dev // args.cp}, seq={args.cp})", flush=True)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
+          f"mesh=(data={n_dev // args.cp}, seq={args.cp}) devices="
+          f"{[d.id for d in mesh.devices.flat]}", flush=True)
     return mesh
 
 
@@ -85,6 +93,9 @@ def main(argv=None):
     ap.add_argument("--attn", default=None,
                     help="attention operator (AttentionSpec.parse name, "
                          "e.g. softmax, fastmax2, fastmax2-kernel)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to this many layers (widths stay "
+                         "at the config's)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -100,8 +111,11 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
-    with _cp_mesh_context(args):
+    mesh = _cp_mesh(args)
+    with (jax.set_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
         cfg = build(args)
         key = jax.random.PRNGKey(0)
         params, axes = init_model(key, cfg)
@@ -157,7 +171,7 @@ def main(argv=None):
         print(f"final loss {np.mean(losses[-10:]):.4f} "
               f"(first10 {np.mean(losses[:10]):.4f}) "
               f"step_stats={mon.stats()}", flush=True)
-        return params
+        return TrainResult(params, losses, mesh)
 
 
 if __name__ == "__main__":

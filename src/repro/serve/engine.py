@@ -174,9 +174,12 @@ class ServeEngine:
         self._check_state = os.environ.get("REPRO_SERVE_CHECK_STATE") == "1"
         self._finite_fn = None                # lazily jitted deep check
 
+        # the pool state is donated: the tick updates it in place rather
+        # than holding the old and the new pool at once
         self._tick_fn = jax.jit(
             functools.partial(_tick, cfg=cfg, axes=self.slots.axes),
-            static_argnames=("do_prefill", "do_decode"))
+            static_argnames=("do_prefill", "do_decode"),
+            donate_argnums=(1,))
 
     # -- submission ----------------------------------------------------------
 

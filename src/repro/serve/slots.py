@@ -134,25 +134,39 @@ class SlotManager:
         self._make = lambda b: to_slotted(init_decode_state(cfg, b, max_len))
         self.axes = slot_batch_axes(self._make)
         self.state = self._make(max_slots)
-        # fresh unit state template, reused for every cold admit (slstm's
-        # `m` lane inits to -1e9 — zeros_like would be wrong)
-        self.fresh_unit = self._make(1)
         self.position = np.zeros(max_slots, np.int32)
         self.active = np.zeros(max_slots, bool)
         self.eos = np.zeros(max_slots, bool)
+        # every write DONATES the pool, so XLA updates the slot in place
+        # instead of holding a second copy of the pool (a fastmax2 slot at
+        # D = Dv = 128 is ~1.9 GB); a cold admit builds the fresh unit
+        # (slstm's `m` lane inits to -1e9 — zeros_like would be wrong)
+        # inside the same launch, so no template stays resident
         self._write = jax.jit(
-            functools.partial(write_slot, axes=self.axes))
+            functools.partial(write_slot, axes=self.axes),
+            donate_argnums=(0,))
+        self._write_fresh = jax.jit(
+            lambda pool, slot: write_slot(pool, self._make(1), slot,
+                                          self.axes),
+            donate_argnums=(0,))
         self._read = jax.jit(
             functools.partial(read_slot, axes=self.axes))
+
+    @property
+    def fresh_unit(self):
+        """A freshly initialized batch-1 unit state (built on demand)."""
+        return self._make(1)
 
     # -- O(1) admit / evict --------------------------------------------------
 
     def admit(self, slot: int, unit_state=None, position: int = 0):
         """Install a unit state (fresh, or a prefix-cache snapshot covering
         `position` tokens) into `slot`."""
-        unit = self.fresh_unit if unit_state is None else unit_state
-        self.state = self._write(self.state, unit,
-                                 jnp.asarray(slot, jnp.int32))
+        slot_ = jnp.asarray(slot, jnp.int32)
+        if unit_state is None:
+            self.state = self._write_fresh(self.state, slot_)
+        else:
+            self.state = self._write(self.state, unit_state, slot_)
         self.position[slot] = position
         self.active[slot] = False
         self.eos[slot] = False
@@ -171,8 +185,8 @@ class SlotManager:
         slot (NaN/Inf leaves) must not sit in the pool where a deep state
         check (`REPRO_SERVE_CHECK_STATE=1`) or a leaky select would see it.
         The slot is immediately reusable."""
-        self.state = self._write(self.state, self.fresh_unit,
-                                 jnp.asarray(slot, jnp.int32))
+        self.state = self._write_fresh(self.state,
+                                       jnp.asarray(slot, jnp.int32))
         self.evict(slot)
 
     def snapshot(self, slot: int):

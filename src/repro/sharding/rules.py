@@ -30,28 +30,15 @@ __all__ = ["DEFAULT_RULES", "spec_for", "param_shardings", "batch_spec",
 
 
 def active_mesh():
-    """The mesh sharding constraints should target, or None.
-
-    One place for the JAX-version-sensitive discovery dance:
-    `get_abstract_mesh` where it exists (newer JAX), falling back to the
-    legacy `with mesh:` thread-resources env (0.4.x — where the abstract-
-    mesh accessor is absent and the naive call raises; a stale copy of
-    this fallback once left `feature_shard_flag` returning False on every
-    call, so keep the logic HERE only)."""
-    mesh = None
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        mesh = None
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        try:
-            from jax._src import mesh as mesh_lib
-            mesh = mesh_lib.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        return None
-    return mesh
+    """The mesh sharding constraints should target, or None: the abstract
+    mesh that `jax.set_mesh` installs, else the concrete mesh of an
+    enclosing `with mesh:` block. Keep this discovery HERE only (a stale
+    copy once left `feature_shard_flag` returning False on every call)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
+        from jax._src import mesh as mesh_lib
+        mesh = mesh_lib.thread_resources.env.physical_mesh
+    return mesh if mesh.axis_names else None
 
 
 def replicate(x, *, batch_dim=None):

@@ -23,6 +23,11 @@ import jax
 import numpy as np
 import pytest
 
+# the launch drivers point JAX's persistent compilation cache at the
+# checkout (`repro.launch.compile_cache`); a test that calls one in-process
+# must not make every later compile in its worker read and write that cache
+jax.config.update("jax_enable_compilation_cache", False)
+
 
 def pytest_collection_modifyitems(items):
     # tier-1 verify loop = everything that isn't a multi-minute subprocess

@@ -275,6 +275,50 @@ def test_fastmax_resumable_prefill_3d_kv_mask_bitwise():
                                       err_msg=name)
 
 
+def test_fastmax_kernel_resumable_prefill_seeds_kernel(monkeypatch):
+    """Under the kernel route a resumable (offset) prefill chunk runs the
+    forward kernel seeded with the carried moments: split at a chunk
+    boundary it matches the whole-prompt kernel prefill (to f64 rounding:
+    the seeded kernel adds the carry in a different order) and the jnp
+    scan route."""
+    from repro.attention import registry as _reg
+
+    monkeypatch.setenv("REPRO_DECODE_KERNEL", "1")
+    rng = np.random.default_rng(23)
+    spec = AttentionSpec(family="fastmax", p=2, impl="kernel", chunk_size=8)
+    b, hq, hkv, n, d = 1, 4, 2, 24, 8
+    q, k, v = mk(rng, b, hq, hkv, n, d, d)
+    mask = (rng.random((b, hkv, n)) < 0.7).astype(np.float64)
+    mask[..., 0] = 1.0
+    mask = jnp.asarray(mask)
+
+    def fresh():
+        return init_state(spec, batch=b, n_kv_heads=hkv, q_head_dim=d,
+                          v_head_dim=d, max_len=n, dtype=jnp.float64)
+
+    o_full, st_full = prefill(q, k, v, spec, state=fresh(), kv_mask=mask)
+    c = 16
+    o1, st = prefill(q[:, :, :c], k[:, :, :c], v[:, :, :c], spec,
+                     state=fresh(), kv_mask=mask[:, :, :c],
+                     offset=jnp.asarray(0, jnp.int32))
+    o2, st = prefill(q[:, :, c:], k[:, :, c:], v[:, :, c:], spec, state=st,
+                     kv_mask=mask[:, :, c:],
+                     offset=jnp.asarray(c, jnp.int32))
+    assert any("kernel seeded with the carried moments" in m
+               for m in _reg._LOGGED)
+    got = jnp.concatenate([o1, o2], axis=2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(o_full),
+                               rtol=1e-12, atol=1e-14)
+    for name, a, ref in zip(st.moments._fields, st.moments,
+                            st_full.moments):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(ref),
+                                   rtol=1e-12, atol=1e-14, err_msg=name)
+    o_jnp, _ = prefill(q, k, v, dataclasses.replace(spec, impl="chunked"),
+                       state=fresh(), kv_mask=mask)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(o_jnp),
+                               rtol=1e-10, atol=1e-12)
+
+
 def test_softmax_resumable_prefill_3d_kv_mask_matches_whole():
     """Same split through the KV-cache resume path: outputs match the
     whole-prompt call and a later decode step sees identical caches (the

@@ -88,12 +88,14 @@ def test_pick_bm_matches_linear_scan(d):
 
 
 @pytest.mark.parametrize("d,dv", [(4, 4), (16, 16), (64, 64), (128, 128),
-                                  (128, 8)])
+                                  (128, 8), (128, 256), (64, 512)])
 def test_pick_blk_matches_linear_scan(d, dv):
+    tileable = [blk for blk in range(1, dv + 1)
+                if dv % blk == 0 and (blk == dv or blk % 128 == 0)]
     for budget in (1, d * d, 1 << 20, 2 << 20):
-        feas = [blk for blk in range(1, dv + 1)
-                if dv % blk == 0 and d * d * blk <= budget]
-        assert pick_blk(d, dv, budget) == (max(feas) if feas else 1)
+        feas = [blk for blk in tileable if d * d * blk <= budget]
+        assert pick_blk(d, dv, budget) == (max(feas) if feas
+                                           else min(tileable))
 
 
 def test_pickers_validate_budget():
@@ -129,14 +131,38 @@ def test_candidates_rejects_unknown_kernel():
 
 
 def test_cost_model_flags_vmem_infeasible():
-    # a 128x128 p=2 head with an unblocked bwd carry pair (2 * D^2 * Dv * 4
-    # = 16 MB of scratch alone) cannot fit 16 MB of VMEM
+    # a 256x256 p=2 head with an unblocked bwd carry pair (2 * D^2 * Dv * 4
+    # = 128 MiB of scratch alone) cannot fit the kernels' VMEM limit; a
+    # 128-wide Dv block of a 128x128 head is lane-tileable and fits, a
+    # 64-wide one does not tile on the TPU at all
+    big = ShapeKey("causal_bwd", 1024, 256, 256, 4, 2, "float32", "cpu")
+    assert math.isinf(cost_model(big, Schedule(bm=1, blk=256, chunk_size=128,
+                                               grid="parallel")))
     key = ShapeKey("causal_bwd", 1024, 128, 128, 4, 2, "float32", "cpu")
-    bad = Schedule(bm=1, blk=128, chunk_size=128, grid="parallel")
+    misaligned = Schedule(bm=1, blk=64, chunk_size=128, grid="parallel")
     good = Schedule(bm=1, blk=pick_blk(128, 128, 1 << 20), chunk_size=128,
                     grid="parallel")
-    assert math.isinf(cost_model(key, bad))
+    assert math.isinf(cost_model(key, misaligned))
     assert math.isfinite(cost_model(key, good))
+
+
+@pytest.mark.parametrize("kernel", autotune.KERNELS)
+def test_schedules_tile_on_tpu_at_128(kernel):
+    """At D = Dv = 128 the pickers and every autotune candidate give blocks
+    the TPU compiler can tile: a Dv block of 128 lanes (nb = 1) and m2 row
+    blocks of a multiple of 8 rows."""
+    key = ShapeKey(kernel, 1 if kernel == "decode" else 4096, 128, 128, 2,
+                   2, "bfloat16", "tpu")
+    budget = (autotune.BWD_BLK_BUDGET if kernel == "causal_bwd"
+              else autotune.FWD_BLK_BUDGET)
+    assert pick_blk(128, 128, budget) == 128
+    assert (pick_bm(128) * 128) % 8 == 0
+    cands = candidate_schedules(kernel, key, 128)
+    assert cands
+    for s in cands:
+        assert s.blk == 128 and (s.bm * 128) % 8 == 0, s
+        assert autotune.tpu_tileable(s, 128, 128)
+        assert math.isfinite(cost_model(key, s)), s
 
 
 # ---------------------------------------------------------------------------
@@ -289,7 +315,7 @@ def test_offline_mode_uses_cache_then_cost_model(monkeypatch, tmp_path):
     assert not path.exists()
 
     # a planted cache entry wins over the cost model
-    planted = Schedule(bm=1, blk=DV, chunk_size=64, grid="arbitrary")
+    planted = Schedule(bm=2, blk=DV, chunk_size=64, grid="arbitrary")
     key = _key("causal_fwd", jnp.float32)
     save_cache(str(path), {key_str(key): {
         "schedule": dict(planted._asdict()), "source": "measured"}})
