@@ -2,7 +2,7 @@ PY := PYTHONPATH=src python
 
 .PHONY: test test-fast test-attention test-kernels test-shard test-serve \
 	test-faults test-cp test-hybrid dryrun-gate bench bench-json \
-	bench-serve bench-tpu ci-fast autotune autotune-check
+	bench-tpu ci-fast autotune autotune-check
 
 # full tier-1 suite (everything, incl. multi-minute subprocess compiles)
 test:
@@ -101,12 +101,6 @@ bench:
 # baseline); prints a fail-soft warning when >20% slower than the baseline
 bench-json:
 	$(PY) -m benchmarks.run --only attn_phases --json BENCH_attention.json
-
-# serving load generator (Poisson arrivals, TTFT/TPOT percentiles,
-# saturation tok/s) -> BENCH_serve.json, the committed serving baseline;
-# prints the same fail-soft >20% regression summary as bench-json
-bench-serve:
-	$(PY) -m benchmarks.serve_load --json BENCH_serve.json
 
 # real-hardware bench lane: same suite as bench-json but refuses to run
 # off-TPU, tunes on silicon (REPRO_AUTOTUNE=1 measures on cache miss), and

@@ -43,12 +43,10 @@ def csv_row(name: str, us_per_call: float, derived: str = "") -> str:
 _LABEL_KEYS = ("interpret", "hardware", "schedule")
 
 
-def regression_summary(baseline: dict, fresh: dict,
-                       tag: str = "bench-json") -> str:
+def regression_summary(baseline: dict, fresh: dict) -> str:
     """One fail-soft line comparing fresh phase timings to the baseline.
 
-    Shared by `benchmarks/run.py` (BENCH_attention.json) and
-    `benchmarks/serve_load.py` (BENCH_serve.json). Only `*_us` keys are
+    Used by `benchmarks/run.py` (BENCH_attention.json). Only `*_us` keys are
     timings; other cell keys are annotations. A suite whose `interpret`,
     `hardware`, or `schedule` label differs from the baseline's is skipped
     entirely: those cells time a different thing (interpret vs compiled,
@@ -58,7 +56,7 @@ def regression_summary(baseline: dict, fresh: dict,
             fresh.get("meta", {}).get("platform") or \
             baseline.get("meta", {}).get("quick") != \
             fresh.get("meta", {}).get("quick"):
-        return (f"{tag}: baseline platform/mode differs — regression "
+        return (f"bench-json: baseline platform/mode differs — regression "
                 f"check skipped")
     slow, skipped = [], []
     for suite, phases in fresh.get("suites", {}).items():
@@ -75,6 +73,6 @@ def regression_summary(baseline: dict, fresh: dict,
     note = (f" (skipped label mismatch: {', '.join(skipped)})"
             if skipped else "")
     if slow:
-        return (f"{tag}: WARNING — >20% slower than baseline: "
+        return (f"bench-json: WARNING — >20% slower than baseline: "
                 + "; ".join(slow) + note)
-    return f"{tag}: OK (no >20% regressions vs baseline){note}"
+    return f"bench-json: OK (no >20% regressions vs baseline){note}"

@@ -26,7 +26,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: fig3,table2,fig6,fig2,"
-                         "table1,fig4,attn_phases,serve")
+                         "table1,fig4,attn_phases")
     ap.add_argument("--json", nargs="?", const="BENCH_attention.json",
                     default=None, metavar="PATH",
                     help="run the attention phase suite and write its "
@@ -49,8 +49,8 @@ def main() -> None:
                      "host (the CPU lane is `make bench-json`)")
 
     from benchmarks import (attention_phases, fig2_dropout, fig3_scaling,
-                            fig4_attnmap, fig6_loss, serve_load,
-                            table1_lra_lite, table2_throughput)
+                            fig4_attnmap, fig6_loss, table1_lra_lite,
+                            table2_throughput)
 
     suites = {
         "fig3": fig3_scaling.run,
@@ -60,7 +60,6 @@ def main() -> None:
         "table1": table1_lra_lite.run,
         "fig4": fig4_attnmap.run,
         "attn_phases": attention_phases.run,
-        "serve": serve_load.run,
     }
     if args.only:
         keep = set(args.only.split(","))
@@ -92,8 +91,7 @@ def main() -> None:
             try:
                 with open(args.json) as f:
                     baseline = json.load(f)
-                print(regression_summary(baseline, fresh, "bench-json"),
-                      flush=True)
+                print(regression_summary(baseline, fresh), flush=True)
             except (json.JSONDecodeError, OSError) as e:
                 print(f"bench-json: baseline unreadable ({e}) — skipping "
                       f"regression check", file=sys.stderr)

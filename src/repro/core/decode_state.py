@@ -32,8 +32,8 @@ def decode_state_bytes(cfg, batch: int, max_len: int) -> int:
     """Bytes of the full-model decode state for `batch` sequences of up to
     `max_len` tokens, WITHOUT allocating it (jax.eval_shape).
 
-    This is the number the serving engine's slot accounting (and the
-    BENCH_serve.json slot-memory cells) report: for fastmax specs it is
+    This is the number the serving engine's slot accounting reports:
+    for fastmax specs it is
     INDEPENDENT of `max_len` (constant moment tuples), for the softmax
     baseline it grows linearly (KV cache rows) — the asymmetry that lets
     `repro.serve` batch 500k-context and 64-token requests into

@@ -223,17 +223,19 @@ def _sinusoidal(n: int, d: int, dtype) -> jnp.ndarray:
 
 
 def _logits(params, x, cfg):
-    if cfg.tie_embeddings:
-        # tied head: scale by 1/sqrt(d) (embeddings are unit-scale at init)
-        logits = jnp.einsum("bnd,vd->bnv", x, params["embed"]) \
-            * (cfg.d_model ** -0.5)
-    else:
-        logits = jnp.einsum("bnd,dv->bnv", x, params["unembed"])
-    if cfg.logits_softcap > 0:
-        c = cfg.logits_softcap
-        logits = c * jnp.tanh(logits / c)
-    if logits.ndim == 3:
-        logits = maybe_constraint(logits, ("pod", "data"), None, "model")
+    with jax.named_scope("logits"):
+        if cfg.tie_embeddings:
+            # tied head: scale by 1/sqrt(d) (embeddings are unit-scale at
+            # init)
+            logits = jnp.einsum("bnd,vd->bnv", x, params["embed"]) \
+                * (cfg.d_model ** -0.5)
+        else:
+            logits = jnp.einsum("bnd,dv->bnv", x, params["unembed"])
+        if cfg.logits_softcap > 0:
+            c = cfg.logits_softcap
+            logits = c * jnp.tanh(logits / c)
+        if logits.ndim == 3:
+            logits = maybe_constraint(logits, ("pod", "data"), None, "model")
     return logits
 
 
@@ -300,10 +302,12 @@ def lm_loss(params, batch, cfg: ModelConfig):
     logits, aux = forward_lm(params, tokens, cfg,
                              embeddings=batch.get("embeddings"),
                              enc_out=batch.get("enc_out"))
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = logz - gold
+    with jax.named_scope("logits"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1)[..., 0]
+        nll = logz - gold
     mask = batch.get("loss_mask")
     if mask is None:
         mask = jnp.ones_like(nll)

@@ -49,7 +49,14 @@ Robustness layer (`serve/errors.py`, `docs/serving.md`):
     spins;
   * `stats()` exposes the counters (admitted / rejected / shed /
     timed_out / cancelled / quarantined / failed / finished, queue depth,
-    slot occupancy) the load generator and CLI report.
+    slot occupancy, prefill and decode ticks and tokens) the CLI and the
+    benchmark report.
+
+Each tick records profiler spans (`serve.step` with its phases
+`serve.admit`, `serve.schedule`, `serve.launch`, `serve.wait`,
+`serve.emit`) on the clock of the device trace; with no profiler running
+they cost about a microsecond each (`docs/serving.md`, "Tracing the
+engine").
 
 Deterministic chaos for all of the above lives in `serve/faults.py`
 (`ServeEngine(..., faults=FaultInjector())`), driven by
@@ -155,7 +162,9 @@ class ServeEngine:
         self.tick_count = 0
         self.decode_tokens = 0        # decode-part tokens (TPOT accounting)
         self.prefill_tokens = 0
-        self.history: List[FinishedRequest] = []   # load-gen latency stats
+        self.decode_ticks = 0         # ticks with a decode part
+        self.prefill_ticks = 0        # ticks with a prefill part
+        self.history: List[FinishedRequest] = []   # terminal records, in order
         self.statuses: Dict[int, RequestStatus] = {}  # rid -> last status
 
         # robustness knobs
@@ -236,8 +245,8 @@ class ServeEngine:
 
     def stats(self) -> Dict[str, int]:
         """Host-side health counters: terminal-outcome totals plus the
-        instantaneous queue / slot occupancy the load generator and CLI
-        report."""
+        instantaneous queue / slot occupancy, and the prefill and decode
+        ticks and tokens."""
         return {
             **self.counters,
             "queue_depth": len(self.scheduler),
@@ -247,6 +256,8 @@ class ServeEngine:
             "ticks": self.tick_count,
             "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
+            "prefill_ticks": self.prefill_ticks,
+            "decode_ticks": self.decode_ticks,
         }
 
     def snapshot(self) -> Dict[str, Any]:
@@ -302,44 +313,55 @@ class ServeEngine:
         failed, timed out, or shed)."""
         self.monitor.start_step()
         self.tick_count += 1
-        if self.faults is not None:
-            self.faults.apply(self, self.tick_count)
-        finished: List[FinishedRequest] = []
-        self._expire_deadlines(finished)
-        self._shed_if_saturated(finished)
-        admitted = self._admit()
+        with jax.profiler.StepTraceAnnotation("serve.step",
+                                              step_num=self.tick_count):
+            if self.faults is not None:
+                self.faults.apply(self, self.tick_count)
+            finished: List[FinishedRequest] = []
+            with jax.profiler.TraceAnnotation("serve.admit"):
+                self._expire_deadlines(finished)
+                self._shed_if_saturated(finished)
+                admitted = self._admit()
 
-        pre = self._pick_prefill()
-        live = self.slots.active & ~self.slots.eos
-        do_decode = bool(live.any())
-        if pre is not None or do_decode:
-            slot = chunk_tok = kv_mask = off = nvalid = None
-            if pre is not None:
-                slot, chunk_tok, kv_mask, off, nvalid = pre
-            state, first_tok, pre_ok, nxt, dec_ok = self._tick_fn(
-                self.params, self.slots.state,
-                None if pre is None else jnp.asarray(slot, jnp.int32),
-                chunk_tok, kv_mask,
-                None if pre is None else jnp.asarray(off, jnp.int32),
-                None if pre is None else jnp.asarray(nvalid, jnp.int32),
-                None if not do_decode else jnp.asarray(self._last_token),
-                None if not do_decode else jnp.asarray(self.slots.position),
-                None if not do_decode else jnp.asarray(live),
-                do_prefill=pre is not None, do_decode=do_decode)
-            self.slots.state = state
+            with jax.profiler.TraceAnnotation("serve.schedule"):
+                pre = self._pick_prefill()
+                live = self.slots.active & ~self.slots.eos
+                do_decode = bool(live.any())
+                slot = chunk_tok = kv_mask = off = nvalid = None
+                pre_args = dec_args = (None,) * 3
+                if pre is not None:
+                    slot, chunk_tok, kv_mask, off, nvalid = pre
+                    pre_args = (jnp.asarray(slot, jnp.int32),
+                                jnp.asarray(off, jnp.int32),
+                                jnp.asarray(nvalid, jnp.int32))
+                if do_decode:
+                    dec_args = (jnp.asarray(self._last_token),
+                                jnp.asarray(self.slots.position),
+                                jnp.asarray(live))
+            if pre is not None or do_decode:
+                self.prefill_ticks += pre is not None
+                self.decode_ticks += do_decode
+                with jax.profiler.TraceAnnotation("serve.launch"):
+                    self.slots.state, *outs = self._tick_fn(
+                        self.params, self.slots.state, pre_args[0],
+                        chunk_tok, kv_mask, *pre_args[1:], *dec_args,
+                        do_prefill=pre is not None, do_decode=do_decode)
+                # the tick's one host sync: every output in one fetch
+                with jax.profiler.TraceAnnotation("serve.wait"):
+                    first_tok, pre_ok, nxt, dec_ok = jax.device_get(outs)
 
-            if pre is not None:
-                self._after_prefill(slot, nvalid, first_tok,
-                                    bool(np.asarray(pre_ok)), finished)
-            if do_decode:
-                self._after_decode(live, np.asarray(nxt),
-                                   np.asarray(dec_ok), finished)
-            if self._check_state:
-                self._deep_state_check(finished)
+                with jax.profiler.TraceAnnotation("serve.emit"):
+                    if pre is not None:
+                        self._after_prefill(slot, nvalid, first_tok,
+                                            bool(pre_ok), finished)
+                    if do_decode:
+                        self._after_decode(live, nxt, dec_ok, finished)
+                    if self._check_state:
+                        self._deep_state_check(finished)
 
-        progressed = bool(admitted or pre is not None or do_decode
-                          or finished)
-        self._watchdog(self.monitor.end_step(), progressed)
+            progressed = bool(admitted or pre is not None or do_decode
+                              or finished)
+            self._watchdog(self.monitor.end_step(), progressed)
         return finished
 
     def run(self, *, max_ticks: int = 1_000_000) -> Dict[int, np.ndarray]:

@@ -42,7 +42,7 @@ class Request:
     eos_id: Optional[int] = None
     callback: Optional[Callable[[int, int], Any]] = None  # (rid, token)
     submit_tick: int = 0               # engine tick at submission
-    submit_time: float = 0.0           # wall clock (load-gen latency stats)
+    submit_time: float = 0.0           # wall clock (deadlines, TTFT)
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     # robustness lane (see serve/errors.py)

@@ -104,7 +104,8 @@ def select_slots(keep, new_state, old_state, axes):
         shape[ax] = keep.shape[0]
         return jnp.where(keep.reshape(shape), n, o)
 
-    return jax.tree.map(sel, new_state, old_state, axes)
+    with jax.named_scope("select_slots"):
+        return jax.tree.map(sel, new_state, old_state, axes)
 
 
 class SlotPool(NamedTuple):
