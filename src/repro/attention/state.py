@@ -57,7 +57,7 @@ from repro.core.fastmax import (
 from repro.core.softmax import softmax_attention
 
 __all__ = ["KVCache", "AttnState", "init_state", "prefill", "step",
-           "use_decode_kernel"]
+           "keep_live", "use_decode_kernel"]
 
 
 def use_decode_kernel(spec: AttentionSpec) -> bool:
@@ -336,13 +336,33 @@ def prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return o.astype(q.dtype), AttnState(kv=None, moments=final)
 
 
+def keep_live(live, new, old):
+    """Per-slot select over a decode-state pytree whose leaves lead with
+    the slot axis: slot i takes `new` where live[i], else keeps `old`.
+    `live` None keeps every slot's `new`."""
+    if live is None:
+        return new
+
+    def sel(n, o):
+        if n.shape[:1] != live.shape:
+            raise ValueError(
+                f"decode-state leaf {n.shape} does not lead with the "
+                f"{live.shape[0]} slots of the live mask")
+        return jnp.where(live.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+
+    return jax.tree.map(sel, new, old)
+
+
 def step(state: AttnState, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-         spec: AttentionSpec):
+         spec: AttentionSpec, *, live: Optional[jnp.ndarray] = None):
     """One-token decode. q:[B,Hq,1,D], k/v:[B,Hkv,1,*].
 
     softmax: append to the cache, attend over the valid prefix.
     fastmax: fold (k, v) into the moments, contract with q —
     O(D^p Dv) per head per token, independent of context length.
+    `live` [B] bool: a slot that is not live keeps its state unchanged
+    (the single-device decode kernel holds it in place; every other path
+    selects per slot over this layer's state); None steps every slot.
     Returns (o [B,Hq,1,Dv], new AttnState).
     """
     _check_state(state, spec)
@@ -388,8 +408,8 @@ def step(state: AttnState, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             from repro.sharding.rules import replicate
             q = replicate(q, batch_dim=0)
         o = softmax_attention(q, kc, vc, causal=False, kv_mask=mask)
-        return o, AttnState(kv=KVCache(kc, vc, kv.length + 1, mc),
-                            moments=None)
+        return o, keep_live(live, AttnState(
+            kv=KVCache(kc, vc, kv.length + 1, mc), moments=None), state)
 
     qh = normalize_qk(q) if spec.normalize else q
     kh = normalize_qk(k) if spec.normalize else k
@@ -402,11 +422,13 @@ def step(state: AttnState, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             o, new_state = fastmax_decode_sharded(
                 qh, kh, v, tuple(state.moments), p=spec.p,
                 denom_eps=spec.denom_eps, plan=plan)
-            return (o.astype(q.dtype),
-                    AttnState(kv=None, moments=Moments(*new_state)))
+            return (o.astype(q.dtype), keep_live(
+                live, AttnState(kv=None, moments=Moments(*new_state)),
+                state))
         if mesh is None:
             o, new_state = kernel_ops.fastmax_decode(
-                qh, kh, v, state.moments, p=spec.p, denom_eps=spec.denom_eps)
+                qh, kh, v, state.moments, p=spec.p, denom_eps=spec.denom_eps,
+                live=live)
             return (o.astype(q.dtype),
                     AttnState(kv=None, moments=Moments(*new_state)))
         _log_once(
@@ -467,4 +489,4 @@ def step(state: AttnState, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         new_kv = KVCache(nk, nv, kv.length + 1, nm)
     o = num / (den + spec.denom_eps)[..., None]
     o = o.reshape(q.shape[0], hq, 1, -1).astype(q.dtype)
-    return o, AttnState(kv=new_kv, moments=new_mom)
+    return o, keep_live(live, AttnState(kv=new_kv, moments=new_mom), state)

@@ -13,6 +13,12 @@ D=Dv=128); fusing update+combine halves HBM traffic vs two separate ops and
 is why this kernel exists. HBM state buffers are reused in place via
 input_output_aliases.
 
+A per-slot live flag (one int32 per (slot, kv head) program, in SMEM,
+after the other operands) lets the serving tick run the step over the
+whole slot pool: a program whose slot is not live writes its moment
+blocks back unchanged and emits zero output rows, so no second pass over
+the pool has to restore the slots that did not decode.
+
 The degree-2 rows of m-block `mb` are built from q̂ᵀ [D, G] and k̂ as a
 [D, 1] column, both passed in that layout, so the block's dynamic offset
 selects sublane rows of a ref (`fastmax_causal._outer_rows`) — Mosaic does
@@ -35,47 +41,67 @@ __all__ = ["fastmax_decode_pallas"]
 
 def _decode_kernel(q_ref, k_ref, v_ref,
                    m0_ref, m1_ref, m2_ref, g0_ref, g1_ref, g2_ref,
-                   qt_ref, kc_ref,
+                   qt_ref, kc_ref, live_ref,
                    o_ref, m0o, m1o, m2o, g0o, g1o, g2o,
                    acc_s, den_s, *, p, bm, nmb, denom_eps, acc):
     mb = pl.program_id(1)
+    live = live_ref[pl.program_id(0)] != 0
     g, d = q_ref.shape[1], q_ref.shape[2]
     dv = v_ref.shape[2]
-    q = q_ref[0].astype(acc)       # [G, D]
-    k = k_ref[0, 0].astype(acc)    # [D]
-    v = v_ref[0, 0].astype(acc)    # [Dv]
 
-    @pl.when(mb == 0)
-    def _small():
-        m0 = m0_ref[0] + v[None, :]
-        m1 = m1_ref[0] + k[:, None] * v[None, :]
-        g0 = g0_ref[0] + 1.0
-        g1 = g1_ref[0] + k[None, :]
-        m0o[0], m1o[0], g0o[0], g1o[0] = m0, m1, g0, g1
-        num = jnp.broadcast_to(m0, (g, dv)) + jnp.dot(
-            q, m1, preferred_element_type=acc)
-        den = g0[0, 0] + jnp.dot(q, g1[0], preferred_element_type=acc)
+    @pl.when(live)
+    def _step():
+        q = q_ref[0].astype(acc)       # [G, D]
+        k = k_ref[0, 0].astype(acc)    # [D]
+        v = v_ref[0, 0].astype(acc)    # [Dv]
+
+        @pl.when(mb == 0)
+        def _small():
+            m0 = m0_ref[0] + v[None, :]
+            m1 = m1_ref[0] + k[:, None] * v[None, :]
+            g0 = g0_ref[0] + 1.0
+            g1 = g1_ref[0] + k[None, :]
+            m0o[0], m1o[0], g0o[0], g1o[0] = m0, m1, g0, g1
+            num = jnp.broadcast_to(m0, (g, dv)) + jnp.dot(
+                q, m1, preferred_element_type=acc)
+            den = g0[0, 0] + jnp.dot(q, g1[0], preferred_element_type=acc)
+            if p >= 2:
+                g2 = g2_ref[0] + k[:, None] * k[None, :]
+                g2o[0] = g2
+                den = den + 0.5 * jnp.sum(
+                    jnp.dot(q, g2, preferred_element_type=acc) * q, axis=-1)
+            else:
+                g2o[0] = g2_ref[0]
+                m2o[0] = m2_ref[0]
+            acc_s[...] = num
+            den_s[...] = den[:, None]
+
         if p >= 2:
-            g2 = g2_ref[0] + k[:, None] * k[None, :]
-            g2o[0] = g2
-            den = den + 0.5 * jnp.sum(
-                jnp.dot(q, g2, preferred_element_type=acc) * q, axis=-1)
-        else:
-            g2o[0] = g2_ref[0]
-            m2o[0] = m2_ref[0]
-        acc_s[...] = num
-        den_s[...] = den[:, None]
+            t = _outer_rows(kc_ref, mb, bm)                     # [bm*D, 1]
+            m2 = m2_ref[0] + t * v[None, :]                      # [bm*D, Dv]
+            m2o[0] = m2
+            y = _outer_rows(qt_ref, mb, bm)                     # [bm*D, G]
+            acc_s[...] += 0.5 * _tn_dot(y, m2, acc)
 
-    if p >= 2:
-        t = _outer_rows(kc_ref, mb, bm)                     # [bm*D, 1]
-        m2 = m2_ref[0] + t * v[None, :]                      # [bm*D, Dv]
-        m2o[0] = m2
-        y = _outer_rows(qt_ref, mb, bm)                     # [bm*D, G]
-        acc_s[...] += 0.5 * _tn_dot(y, m2, acc)
+        @pl.when(mb == nmb - 1)
+        def _emit():
+            o_ref[0] = (acc_s[...] / (den_s[...] + denom_eps)).astype(
+                o_ref.dtype)
 
-    @pl.when(mb == nmb - 1)
-    def _emit():
-        o_ref[0] = (acc_s[...] / (den_s[...] + denom_eps)).astype(o_ref.dtype)
+    # a slot that is not live streams its state back unchanged (the
+    # outputs alias the inputs, so every block must still be written)
+    @pl.when(jnp.logical_not(live))
+    def _hold():
+        @pl.when(mb == 0)
+        def _small():
+            m0o[0], m1o[0], g0o[0] = m0_ref[0], m1_ref[0], g0_ref[0]
+            g1o[0], g2o[0] = g1_ref[0], g2_ref[0]
+
+        m2o[0] = m2_ref[0]
+
+        @pl.when(mb == nmb - 1)
+        def _emit():
+            o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
 
 @functools.partial(
@@ -88,6 +114,7 @@ def fastmax_decode_pallas(
     v: jnp.ndarray,   # [B, Hkv, 1, Dv]
     state: tuple,     # Moments with shapes [B,Hkv,Dv],[B,Hkv,D,Dv],
                       # [B,Hkv,D,D,Dv],[B,Hkv],[B,Hkv,D],[B,Hkv,D,D]
+    live: jnp.ndarray | None = None,  # [B] bool; None = every slot live
     *,
     p: int = 2,
     denom_eps: float = 1e-6,
@@ -120,6 +147,10 @@ def fastmax_decode_pallas(
     g0r = g0.reshape(bh, 1, 1).astype(acc)
     g1r = g1.reshape(bh, 1, d).astype(acc)
     g2r = g2.reshape(bh, d, d).astype(acc)
+    if live is None:
+        live = jnp.ones((b,), jnp.int32)
+    # one flag per (slot, kv head) program, read as a scalar from SMEM
+    lv = jnp.repeat(live.astype(jnp.int32), hkv)
 
     if bm is None:
         bm = pick_bm(d)
@@ -151,6 +182,7 @@ def fastmax_decode_pallas(
             pl.BlockSpec((1, d, d), sm),
             pl.BlockSpec((d, g), lambda h, mb: (h, 0)),
             pl.BlockSpec((d, 1), lambda h, mb: (h, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, g, dv), lambda h, mb: (h, 0, 0)),
@@ -180,7 +212,7 @@ def fastmax_decode_pallas(
         compiler_params=compiler_params((grid, "arbitrary")),
         interpret=interpret,
         name=f"fastmax_decode_p{p}",
-    )(qr, kr, vr, m0r, m1r, m2r, g0r, g1r, g2r, qt, kc)
+    )(qr, kr, vr, m0r, m1r, m2r, g0r, g1r, g2r, qt, kc, lv)
 
     o, m0n, m1n, m2n, g0n, g1n, g2n = outs
     o = o.reshape(b, hq, 1, dv)

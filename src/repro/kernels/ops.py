@@ -317,9 +317,11 @@ def fastmax_prefill_kernel(
 
 def fastmax_decode(
     q, k, v, state, *, p: int = 2, denom_eps: float = 1e-6,
-    interpret: bool | None = None, schedule=None,
+    interpret: bool | None = None, schedule=None, live=None,
 ):
-    """Kernel-backed single-token decode step on moment-tuple state."""
+    """Kernel-backed single-token decode step on moment-tuple state.
+    `live` [B] bool: a slot that is not live keeps its state bit for bit
+    (and gets zero output rows); None means every slot is live."""
     if interpret is None:
         interpret = use_interpret()
     if schedule is None:
@@ -327,5 +329,5 @@ def fastmax_decode(
     dk = {} if schedule is None else {"bm": schedule.bm,
                                       "grid": schedule.grid}
     return fastmax_decode_pallas(
-        q, k, v, tuple(state), p=p, denom_eps=denom_eps, interpret=interpret,
-        **dk)
+        q, k, v, tuple(state), live, p=p, denom_eps=denom_eps,
+        interpret=interpret, **dk)

@@ -36,14 +36,17 @@ from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import init_decode_state, init_model
 
 # jitted prefill/step per config — reused across generate() calls so a
-# warmup call actually warms the timed call (cfg is frozen/hashable)
+# warmup call actually warms the timed call (cfg is frozen/hashable). The
+# step donates the decode state: its layer scan carries the state and
+# updates it in place, which a state it may not overwrite would first copy
 _JIT_CACHE: dict = {}
 
 
 def _jitted_steps(cfg):
     fns = _JIT_CACHE.get(cfg)
     if fns is None:
-        fns = (jax.jit(make_prefill_step(cfg)), jax.jit(make_serve_step(cfg)))
+        fns = (jax.jit(make_prefill_step(cfg)),
+               jax.jit(make_serve_step(cfg), donate_argnums=(1,)))
         _JIT_CACHE[cfg] = fns
     return fns
 

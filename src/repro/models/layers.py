@@ -215,15 +215,17 @@ def init_attn_state(cfg, batch: int, max_len: int, dtype) -> AttnState:
                         max_len=max_len, dtype=dtype)
 
 
-def attention_decode(params, x_t, state: AttnState, cfg, *, position):
+def attention_decode(params, x_t, state: AttnState, cfg, *, position,
+                     live=None):
     """One-token decode. x_t: [B, 1, d]. Returns (y_t, new_state).
 
     `position` is a scalar (shared timeline — the legacy serve loop) or a
     [B] vector (slot-indexed serving: every sequence sits at its own
-    context length, so RoPE must rotate per slot)."""
+    context length, so RoPE must rotate per slot). `live` [B] bool keeps
+    the state of the slots that are not live unchanged."""
     pos = jnp.atleast_1d(jnp.asarray(position, jnp.int32))[:, None]
     q, k, v = _project_qkv(params, x_t, cfg, pos)
-    o, new = A.step(state, q, k, v, cfg.attn_spec)
+    o, new = A.step(state, q, k, v, cfg.attn_spec, live=live)
     y = jnp.einsum("bhnk,hkd->bnd", o.astype(x_t.dtype), params["wo"])
     return y, new
 

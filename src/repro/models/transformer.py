@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.attention import AttentionSpec
+from repro.attention.state import keep_live
 from repro.models import layers as L
 from repro.sharding.rules import maybe_constraint
 from repro.models import mamba as M
@@ -349,19 +350,19 @@ def init_lm_decode_state(cfg: ModelConfig, batch: int, max_len: int):
     return state
 
 
-def _decode_block(params, x_t, st, kind, cfg, *, position, enc_out=None):
+def _decode_block(params, x_t, st, kind, cfg, *, position, enc_out=None,
+                  live=None):
     mixer, ffn = kind.split(":")
     h = L.apply_norm(params["norm1"], x_t, norm_type=cfg.norm_type,
                      eps=cfg.norm_eps)
     if mixer == "attn":
         y, st = L.attention_decode(params["mixer"], h, st, cfg,
-                                   position=position)
-    elif mixer == "mamba":
-        y, st = M.mamba_decode(params["mixer"], h, st, cfg)
-    elif mixer == "mlstm":
-        y, st = X.mlstm_decode(params["mixer"], h, st, cfg)
-    elif mixer == "slstm":
-        y, st = X.slstm_decode(params["mixer"], h, st, cfg)
+                                   position=position, live=live)
+    else:
+        decode = {"mamba": M.mamba_decode, "mlstm": X.mlstm_decode,
+                  "slstm": X.slstm_decode}[mixer]
+        y, new = decode(params["mixer"], h, st, cfg)
+        st = keep_live(live, new, st)
     x_t = x_t + y
     if mixer == "attn" and cfg.cross_attention and enc_out is not None:
         h = L.apply_norm(params["norm_x"], x_t, norm_type=cfg.norm_type,
@@ -380,9 +381,15 @@ def _decode_block(params, x_t, st, kind, cfg, *, position, enc_out=None):
 
 
 def lm_decode_step(params, state, token_t, cfg: ModelConfig, *, position,
-                   enc_out=None):
+                   enc_out=None, live=None):
     """One token for the whole model. token_t: [B] int32. Returns
-    (logits [B, vocab], new_state)."""
+    (logits [B, vocab], new_state).
+
+    `live` [B] bool (slot-pooled serving): a slot that is not live keeps
+    its decode state unchanged, layer by layer; None steps every slot.
+    The stacked layer states ride the layer scan as a carry, so the whole
+    state stays one buffer from input to output (a donated pool is
+    updated in place)."""
     x = params["embed"][token_t][:, None].astype(cfg.adtype())
     if cfg.pos_emb == "sinusoidal":
         d = cfg.d_model
@@ -396,25 +403,32 @@ def lm_decode_step(params, state, token_t, cfg: ModelConfig, *, position,
     for i in range(cfg.first_k_dense):
         x, st = _decode_block(params[f"dense_{i}"], x, state[f"dense_{i}"],
                               cfg.pattern[0], cfg, position=position,
-                              enc_out=enc_out)
+                              enc_out=enc_out, live=live)
         state = {**state, f"dense_{i}": st}
 
     def group_body(carry, xs):
-        x_t = carry
-        group_params, group_state = xs
-        new_states = {}
+        x_t, pool = carry
+        group_params, g = xs
         for i, kind in enumerate(cfg.pattern):
-            x_t, st = _decode_block(group_params[f"blocks_{i}"], x_t,
-                                    group_state[f"blocks_{i}"], kind, cfg,
-                                    position=position, enc_out=enc_out)
-            new_states[f"blocks_{i}"] = st
-        return x_t, new_states
+            key = f"blocks_{i}"
+            st = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False),
+                pool[key])
+            x_t, st = _decode_block(group_params[key], x_t, st, kind, cfg,
+                                    position=position, enc_out=enc_out,
+                                    live=live)
+            pool = {**pool, key: jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, g, 0),
+                pool[key], st)}
+        return (x_t, pool), None
 
     stacked_p = {f"blocks_{i}": params[f"blocks_{i}"]
                  for i in range(len(cfg.pattern))}
     stacked_s = {f"blocks_{i}": state[f"blocks_{i}"]
                  for i in range(len(cfg.pattern))}
-    x, new_stacked = jax.lax.scan(group_body, x, (stacked_p, stacked_s))
+    (x, new_stacked), _ = jax.lax.scan(
+        group_body, (x, stacked_s),
+        (stacked_p, jnp.arange(cfg.n_groups, dtype=jnp.int32)))
     state = {**state, **new_stacked}
     x = L.apply_norm(params["final_norm"], x, norm_type=cfg.norm_type,
                      eps=cfg.norm_eps)

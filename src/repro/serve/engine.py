@@ -13,7 +13,9 @@ parts are present):
   decode part   -> every active slot takes one `lm_decode_step` with its
                    own last token and its own position lane; slots that
                    are inactive / mid-prefill ride through the batched
-                   compute and are restored by `select_slots`.
+                   compute with a `live` flag off, which keeps their state
+                   unchanged layer by layer (inside the decode kernel,
+                   or by a per-layer select elsewhere).
 
 All backends route through the same `init_state`/`prefill`/`step`
 protocol, so the engine works unchanged for softmax-KV, fastmax (chunked
@@ -80,7 +82,7 @@ from repro.serve.errors import (TERMINAL_STATUSES, EngineOverloaded,
                                 EngineStalled, RequestStatus)
 from repro.serve.prefix_cache import PrefixCache
 from repro.serve.scheduler import Request, Scheduler
-from repro.serve.slots import SlotManager, read_slot, select_slots, write_slot
+from repro.serve.slots import SlotManager, read_slot, write_slot
 
 __all__ = ["ServeEngine", "FinishedRequest"]
 
@@ -163,6 +165,7 @@ class ServeEngine:
         self.decode_tokens = 0        # decode-part tokens (TPOT accounting)
         self.prefill_tokens = 0
         self.decode_ticks = 0         # ticks with a decode part
+        self.decode_masked_slots = 0  # slot-ticks a decode part held as is
         self.prefill_ticks = 0        # ticks with a prefill part
         self.history: List[FinishedRequest] = []   # terminal records, in order
         self.statuses: Dict[int, RequestStatus] = {}  # rid -> last status
@@ -258,6 +261,7 @@ class ServeEngine:
             "decode_tokens": self.decode_tokens,
             "prefill_ticks": self.prefill_ticks,
             "decode_ticks": self.decode_ticks,
+            "decode_masked_slots": self.decode_masked_slots,
         }
 
     def snapshot(self) -> Dict[str, Any]:
@@ -341,6 +345,8 @@ class ServeEngine:
             if pre is not None or do_decode:
                 self.prefill_ticks += pre is not None
                 self.decode_ticks += do_decode
+                if do_decode:
+                    self.decode_masked_slots += int((~live).sum())
                 with jax.profiler.TraceAnnotation("serve.launch"):
                     self.slots.state, *outs = self._tick_fn(
                         self.params, self.slots.state, pre_args[0],
@@ -706,11 +712,10 @@ def _tick(params, state, slot, chunk_tok, kv_mask, off, nvalid,
         state = write_slot(state, unit, slot, axes)
     nxt = dec_ok = None
     if do_decode:
-        logits, new_state = lm_decode_step(params, state, tokens, cfg,
-                                           position=positions)
+        logits, state = lm_decode_step(params, state, tokens, cfg,
+                                       position=positions, live=live)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         dec_ok = jnp.isfinite(logits).all(axis=-1)
-        state = select_slots(live, new_state, state, axes)
         nxt = jnp.where(live, nxt, tokens)
     return state, first_tok, pre_ok, nxt, dec_ok
 

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from repro.attention.state import KVCache
 
 __all__ = ["SlotPool", "SlotManager", "to_slotted", "slot_batch_axes",
-           "write_slot", "read_slot", "select_slots"]
+           "write_slot", "read_slot"]
 
 
 def to_slotted(state: Any):
@@ -93,19 +93,6 @@ def read_slot(pool_state, slot, axes):
         return jax.lax.dynamic_slice_in_dim(p, slot, 1, axis=ax)
 
     return jax.tree.map(r, pool_state, axes)
-
-
-def select_slots(keep, new_state, old_state, axes):
-    """Per-slot select: keep[i] ? new : old for every leaf. Used by the
-    engine's decode tick so inactive / mid-prefill slots are untouched by
-    the batched step that ran over them."""
-    def sel(n, o, ax):
-        shape = [1] * n.ndim
-        shape[ax] = keep.shape[0]
-        return jnp.where(keep.reshape(shape), n, o)
-
-    with jax.named_scope("select_slots"):
-        return jax.tree.map(sel, new_state, old_state, axes)
 
 
 class SlotPool(NamedTuple):
@@ -184,7 +171,7 @@ class SlotManager:
         """Free a slot AND re-initialize its device state from the fresh
         template. Unlike `evict`, the state write matters here: a poisoned
         slot (NaN/Inf leaves) must not sit in the pool where a deep state
-        check (`REPRO_SERVE_CHECK_STATE=1`) or a leaky select would see it.
+        check (`REPRO_SERVE_CHECK_STATE=1`) would see it.
         The slot is immediately reusable."""
         self.state = self._write_fresh(self.state,
                                        jnp.asarray(slot, jnp.int32))

@@ -74,6 +74,35 @@ def test_decode_kernel_stream(p):
         state = st
 
 
+@pytest.mark.parametrize("mask", [(1, 1, 1), (1, 0, 1), (0, 0, 0)],
+                         ids=["all_live", "mixed", "all_dead"])
+@pytest.mark.parametrize("p", [1, 2])
+def test_decode_kernel_live_mask(p, mask):
+    """A slot that is not live keeps its six moment leaves bit for bit; a
+    live slot's state and output rows equal the unmasked kernel's."""
+    from repro.kernels.fastmax_decode import fastmax_decode_pallas
+    rng = np.random.default_rng(14)
+    B, Hq, Hkv, D, Dv = 3, 4, 2, 8, 8
+    state = tuple(init_fastmax_state(B, Hkv, D, Dv, p=p))
+    for _ in range(2):      # a state that is not all zeros
+        q, k, v = mk(rng, B, Hq, Hkv, 1, D, Dv, jnp.float32)
+        _, state = fastmax_decode_pallas(q, k, v, state, p=p, bm=4,
+                                         interpret=True)
+    q, k, v = mk(rng, B, Hq, Hkv, 1, D, Dv, jnp.float32)
+    live = np.asarray(mask, bool)
+    o_all, st_all = fastmax_decode_pallas(q, k, v, state, p=p, bm=4,
+                                          interpret=True)
+    o, st = fastmax_decode_pallas(q, k, v, state, jnp.asarray(live), p=p,
+                                  bm=4, interpret=True)
+    np.testing.assert_array_equal(np.asarray(o)[live],
+                                  np.asarray(o_all)[live])
+    assert len(st) == 6
+    for new, old, unmasked in zip(st, state, st_all):
+        new, old, unmasked = map(np.asarray, (new, old, unmasked))
+        np.testing.assert_array_equal(new[live], unmasked[live])
+        np.testing.assert_array_equal(new[~live], old[~live])
+
+
 def test_kernel_gradient_matches_chunked():
     """Kernel fwd pairs with the §2.5 reversible backward."""
     import repro.core.fastmax as fm

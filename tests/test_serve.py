@@ -82,6 +82,139 @@ def test_slot_memory_constant_for_fastmax():
 
 
 # ---------------------------------------------------------------------------
+# the decode tick's live mask
+# ---------------------------------------------------------------------------
+
+# (spec, arch): the decode kernel (interpret), the jnp moment step, the
+# softmax KV cache and a recurrent mixer
+LIVE_CASES = [("fastmax2-kernel", "qwen3-1.7b"),
+              ("fastmax2-chunked", "qwen3-1.7b"),
+              ("softmax", "qwen3-1.7b"),
+              (None, "xlstm-1.3b")]
+
+
+def _live_setup(monkeypatch, spec, arch):
+    if spec == "fastmax2-kernel":
+        monkeypatch.setenv("REPRO_DECODE_KERNEL", "1")
+    return _setup(spec, arch=arch)
+
+
+def _select_per_slot(live, new, old, axes):
+    """Reference: keep slot i's new state where live[i], else its old
+    state, leaf by leaf along each leaf's slot axis."""
+    def sel(n, o, ax):
+        shape = [1] * n.ndim
+        shape[ax] = live.shape[0]
+        return jnp.where(live.reshape(shape), n, o)
+
+    return jax.tree.map(sel, new, old, axes)
+
+
+@pytest.mark.parametrize("spec,arch", LIVE_CASES)
+def test_decode_step_live_mask_matches_select(monkeypatch, spec, arch):
+    """lm_decode_step(live=mask) equals the unmasked step followed by a
+    per-slot select over the whole state, and its live slots' logits
+    equal the unmasked step's. Slots that are not live keep their state
+    bit for bit. The attention paths match bit for bit on live slots too;
+    for the recurrent mixer XLA fuses the sLSTM cell differently once a
+    select consumes it, which moves its cell state by a few float32 ulps
+    (as an all-live mask does), so live slots there match to float32
+    rounding."""
+    from repro.models.transformer import lm_decode_step
+    cfg, params = _live_setup(monkeypatch, spec, arch)
+    sm = SlotManager(cfg, max_slots=3, max_len=32)
+
+    @jax.jit
+    def step(state, tok, pos, live):
+        return lm_decode_step(params, state, tok, cfg, position=pos,
+                              live=live)
+
+    @jax.jit
+    def step_all(state, tok, pos):
+        return lm_decode_step(params, state, tok, cfg, position=pos)
+
+    pos = jnp.asarray([0, 0, 0], jnp.int32)
+    _, state = step_all(sm.state, jnp.asarray([3, 5, 7], jnp.int32), pos)
+    tok = jnp.asarray([11, 13, 17], jnp.int32)
+    live = jnp.asarray([True, False, True])
+    logits, got = step(state, tok, pos + 1, live)
+    logits_all, new = step_all(state, tok, pos + 1)
+    want = _select_per_slot(live, new, state, sm.axes)
+    exact = spec is not None      # the attention cases; xlstm has none
+    for a, b, ax in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                        jax.tree.leaves(sm.axes)):
+        a, b = np.moveaxis(np.asarray(a), ax, 0), np.moveaxis(
+            np.asarray(b), ax, 0)
+        np.testing.assert_array_equal(a[1], b[1])
+        if exact:
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    live_rows = np.asarray(logits)[[0, 2]], np.asarray(logits_all)[[0, 2]]
+    if exact:
+        np.testing.assert_array_equal(*live_rows)
+    else:
+        np.testing.assert_allclose(*live_rows, rtol=1e-5, atol=1e-6)
+
+
+def _tensor_type(shape, dtype) -> str:
+    dt = {"float32": "f32", "int32": "i32", "bool": "i1"}[str(dtype)]
+    return "tensor<" + "".join(f"{n}x" for n in shape) + dt + ">"
+
+
+@pytest.mark.parametrize("spec,arch", LIVE_CASES)
+def test_decode_tick_updates_pool_in_place(monkeypatch, spec, arch):
+    """The lowered decode tick has no select over a whole-pool-shaped
+    tensor, and its layer scan carries each pool leaf exactly once (a
+    scan with the pool as xs and ys would carry it twice)."""
+    import collections
+    import re
+    cfg, params = _live_setup(monkeypatch, spec, arch)
+    eng = ServeEngine(params, cfg, max_slots=3, max_len=32)
+    b = eng.slots.max_slots
+    text = eng._tick_fn.lower(
+        params, eng.slots.state, None, None, None, None, None,
+        jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32),
+        jnp.ones(b, bool), do_prefill=False, do_decode=True).as_text()
+    stacked = [leaf for key, sub in eng.slots.state.items()
+               if key.startswith("blocks_") for leaf in jax.tree.leaves(sub)]
+    pool = collections.Counter(_tensor_type(x.shape, x.dtype)
+                               for x in stacked)
+    assert pool
+    for line in text.splitlines():
+        if "stablehlo.select" in line:
+            assert line.rsplit(": ", 1)[-1].split(", ")[-1] not in pool, line
+    carries = [collections.Counter(
+        t for t in re.findall(r"tensor<[^>]*>", line.rsplit(") : ", 1)[-1])
+        if t in pool) for line in text.splitlines()
+        if "stablehlo.while" in line]
+    assert pool in carries, carries
+
+
+def test_decode_masked_slots_counts_a_mid_prefill_slot():
+    """A slot still prefilling rides through the decode part unchanged,
+    and each such slot-tick is counted."""
+    cfg, params = _setup("fastmax2-chunked")
+    rng = np.random.default_rng(8)
+    eng = ServeEngine(params, cfg, max_slots=2, max_len=64, chunk=4)
+    eng.submit(rng.integers(0, cfg.vocab_size, 4).astype(np.int32), 8)
+    eng.submit(rng.integers(0, cfg.vocab_size, 12).astype(np.int32), 2)
+    eng.step()          # slot 0's whole prompt: no decode part yet
+    assert eng.stats()["decode_masked_slots"] == 0
+    eng.step()          # slot 0 decodes, slot 1 prefills chunk 1 of 3
+    eng.step()          # chunk 2 of 3
+    st = eng.stats()
+    assert (st["decode_ticks"], st["decode_masked_slots"]) == (2, 2)
+    eng.run()
+    st = eng.stats()
+    # slot 1 is held while it prefills (three ticks) and again once it is
+    # free (the last three of slot 0's seven decode ticks)
+    assert st["decode_ticks"] == 7
+    assert st["decode_masked_slots"] == 3 + 3
+    assert st["decode_tokens"] == 7 + 1
+
+
+# ---------------------------------------------------------------------------
 # engine vs generate(): token parity
 # ---------------------------------------------------------------------------
 

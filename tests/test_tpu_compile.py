@@ -7,6 +7,9 @@ refuses here (block tiling, unsupported primitives, scoped-VMEM overflow)
 it would refuse on the chip. The topology is described inside a module
 fixture, which skips where libtpu cannot describe it.
 """
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +22,7 @@ from repro.kernels.fastmax_noncausal import fastmax_noncausal_pallas
 from repro.kernels.hybrid_causal import hybrid_causal_pallas
 
 B, HQ, HKV, N, D = 1, 16, 8, 1024, 128
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -54,6 +58,7 @@ def shapes(topo):
         "q1": s((4, HQ, 1, D)), "k1": s((4, HKV, 1, D)),
         "v1": s((4, HKV, 1, D)),
         "state4": tuple(s((4,) + x.shape[1:], f32) for x in state),
+        "live4": s((4,), jnp.bool_),
     }
 
 
@@ -92,6 +97,13 @@ def _decode(sh):
         sh["q1"], sh["k1"], sh["v1"], sh["state4"])
 
 
+def _decode_live(sh):
+    return _compile(
+        lambda q, k, v, st, live: fastmax_decode_pallas(q, k, v, st, live,
+                                                        p=2),
+        sh["q1"], sh["k1"], sh["v1"], sh["state4"], sh["live4"])
+
+
 def _noncausal(sh):
     return _compile(lambda q, k, v: fastmax_noncausal_pallas(q, k, v, p=2),
                     sh["q"], sh["k"], sh["v"])
@@ -111,8 +123,52 @@ def _hybrid(sh):
     pytest.param(_bwd(False), id="causal_bwd"),
     pytest.param(_bwd(True), id="causal_bwd-return_dstate"),
     pytest.param(_decode, id="decode"),
+    pytest.param(_decode_live, id="decode-live"),
     pytest.param(_noncausal, id="noncausal"),
     pytest.param(_hybrid, id="hybrid"),
 ])
 def test_kernel_compiles_for_v5e(shapes, case):
     case(shapes)
+
+
+def test_decode_kernel_counts_unchanged_by_live_flag(topo):
+    """The live flag adds one int32 per (slot, kv head) to the decode
+    kernel's operands: at qwen3-1.7b's serving widths (3 slots, 8 kv
+    heads, D = Dv = 128) the benchmark's operation and byte counts of a
+    call read as they do for the kernel without it (409 MB a call)."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from bench.counts import kernel_calls, kernel_cost
+
+    one = SingleDeviceSharding(topo.devices[0])
+    b, bh, g = 3, 3 * HKV, HQ // HKV
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    state = [(b, HKV, D), (b, HKV, D, D), (b, HKV, D, D, D), (b, HKV),
+             (b, HKV, D), (b, HKV, D, D)]
+    text = jax.jit(
+        lambda q, k, v, st, live: fastmax_decode_pallas(q, k, v, st, live,
+                                                        p=2)
+    ).lower(s((b, HQ, 1, D), bf16), s((b, HKV, 1, D), bf16),
+            s((b, HKV, 1, D), bf16), tuple(s(x, f32) for x in state),
+            s((b,), jnp.bool_)).as_text()
+    (call,) = kernel_calls(text)
+    assert call[0] == "fastmax_decode_p2"
+    ops, nbytes = kernel_cost(*call)
+    # the kernel's operands and results as they were without the flag:
+    # q, k, v, the six moments, q-hat transposed and the k-hat column;
+    # the output rows and the six moments
+    moments = [((bh, 1, D), "f32"), ((bh, D, D), "f32"),
+               ((bh, D * D, D), "f32"), ((bh, 1, 1), "f32"),
+               ((bh, 1, D), "f32"), ((bh, D, D), "f32")]
+    before = ([((bh, g, D), "bf16"), ((bh, 1, D), "bf16"),
+               ((bh, 1, D), "bf16")] + moments
+              + [((bh * D, g), "f32"), ((bh * D, 1), "f32")],
+              [((bh, g, D), "bf16")] + moments)
+    ops0, nbytes0 = kernel_cost("fastmax_decode_p2", *before)
+    assert ops == ops0
+    assert abs(nbytes - nbytes0) <= 1024
+    assert 408e6 < nbytes0 < 410e6
