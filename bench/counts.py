@@ -2,12 +2,20 @@
 kernel's operations and bytes per call, from the operand and result shapes
 of the `tpu_custom_call`s in the lowered programs the window runs.
 
+A layer's forward FLOPs per token are the cell's reference's
+(`layer_flops_per_token` in `bench/refs/<reference>.py`): the work the
+architecture really does per token, the routed top-k and shared experts
+of a sparse layer and never all of them, and the mean over the layers
+where they differ. This module adds the logits and the training passes.
+
 Attention counts as fastmax's moment update plus query combine (the FAST
 paper's factorized form, Eqs. 26-27): per token, per kv head for the update
 and per query head for the combine, 2 (1 + D + D^2)(Dv + 1) operations at
 order 2. The quadratic work inside a chunk and recomputation are not
 counted, so a roofline share computed from these counts cannot pass 100%
-through an inflated count.
+through an inflated count. `kernel_cost` knows the three fastmax kernels;
+a later kernel's operation and byte count goes in a new file under
+`bench/`, which its roofline reader imports.
 """
 from __future__ import annotations
 
@@ -81,28 +89,18 @@ def kernel_cost(name: str, operands, results):
     return ops, nbytes(operands) + nbytes(results)
 
 
-def layer_flops_per_token(s: dict) -> float:
-    """Forward FLOPs of one decoder block for one token: the projections,
-    the SwiGLU MLP and fastmax's update and combine."""
-    d, hq, hkv, hd, ff = s["d"], s["hq"], s["hkv"], s["hd"], s["ff"]
-    proj = 2.0 * d * hd * (2 * hq + 2 * hkv)
-    mlp = 2.0 * 3 * d * ff
-    return proj + mlp + attn_ops_per_token(hkv, hd, hd) \
-        + attn_ops_per_token(hq, hd, hd)
-
-
 def logits_flops(s: dict) -> float:
     return 2.0 * s["d"] * s["V"]
 
 
-def forward_flops(s: dict, tokens: int, logit_rows: int) -> float:
+def forward_flops(ref, s: dict, tokens: int, logit_rows: int) -> float:
     """Forward FLOPs of `tokens` tokens through every layer, with logits
-    for `logit_rows` of them."""
-    return tokens * s["L"] * layer_flops_per_token(s) \
+    for `logit_rows` of them; `ref` is the cell's reference module."""
+    return tokens * s["L"] * ref.layer_flops_per_token(s) \
         + logit_rows * logits_flops(s)
 
 
-def train_flops(s: dict, tokens: int) -> float:
+def train_flops(ref, s: dict, tokens: int) -> float:
     """Forward and backward (twice the forward) of a training step with
     logits on every token; recomputation is not counted."""
-    return 3.0 * forward_flops(s, tokens, tokens)
+    return 3.0 * forward_flops(ref, s, tokens, tokens)

@@ -6,30 +6,64 @@ from __future__ import annotations
 import dataclasses
 
 
+# published key -> the program's ModelConfig field, and how to read it.
+# Several published names for one quantity map to the same field.
+PUBLISHED = {
+    "vocab_size": ("vocab_size", int),
+    "hidden_size": ("d_model", int),
+    "num_hidden_layers": ("n_layers", int),
+    "num_attention_heads": ("n_heads", int),
+    "num_key_value_heads": ("n_kv_heads", int),
+    "head_dim": ("head_dim", int),
+    "intermediate_size": ("d_ff", int),
+    "rms_norm_eps": ("norm_eps", float),
+    "rope_theta": ("rope_theta", float),
+    "tie_word_embeddings": ("tie_embeddings", bool),
+    "num_experts": ("n_experts", int),
+    "n_routed_experts": ("n_experts", int),
+    "num_experts_per_tok": ("moe_top_k", int),
+    "moe_intermediate_size": ("d_ff_expert", int),
+    "n_shared_experts": ("n_shared_experts", int),
+    "first_k_dense_replace": ("first_k_dense", int),
+    "torch_dtype": ("param_dtype", str),
+}
+# published hidden_act -> the program's `mlp_act` that computes it (its
+# other MLP, an ungated tanh-approximated GELU, is no published one)
+MLP_ACT = {"silu": "swiglu"}
+
+
 def model_config(cfg: dict):
     """The program's ModelConfig for a configuration file: the program's
-    own preset for `run.arch`, with every published size taken from the
-    file and the attention operator from `run.attention`."""
+    own preset for `run.arch`; every published key of `PUBLISHED` the file
+    has; then `run.program`, fields the published file cannot state (a
+    layer pattern, say); and the attention operator from `run.attention`.
+    What the file states and the preset cannot do is an error."""
     from repro.attention import AttentionSpec
     from repro.configs import get_config
 
     run = cfg["run"]
     base = get_config(run["arch"])
-    if cfg["hidden_act"] != "silu" or base.mlp_act != "swiglu":
-        raise ValueError("the qwen3 path serves a SiLU-gated MLP only")
-    if cfg["attention_bias"] or base.qkv_bias:
-        raise ValueError("the qwen3 path has no attention bias")
-    return dataclasses.replace(
-        base, vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        param_dtype=cfg["torch_dtype"], activ_dtype=cfg["torch_dtype"],
-        chunk_size=int(run["chunk_size"]),
-        attn=AttentionSpec.parse(run["attention"]))
+    fields = {f.name for f in dataclasses.fields(base)}
+    over = {field: read(cfg[key]) for key, (field, read) in PUBLISHED.items()
+            if key in cfg}
+    over["activ_dtype"] = over.get("param_dtype", base.activ_dtype)
+    extra = run.get("program", {})
+    unknown = set(extra) - fields
+    if unknown:
+        raise ValueError(f"run.program names no ModelConfig field: "
+                         f"{sorted(unknown)}")
+    # JSON lists are the config's tuples (a layer pattern)
+    over.update({k: tuple(v) if isinstance(v, list) else v
+                 for k, v in extra.items()})
+    mcfg = dataclasses.replace(base, **over, chunk_size=int(run["chunk_size"]),
+                               attn=AttentionSpec.parse(run["attention"]))
+    if MLP_ACT.get(cfg["hidden_act"]) != mcfg.mlp_act:
+        raise ValueError(f"hidden_act {cfg['hidden_act']!r} is not the "
+                         f"preset's MLP {mcfg.mlp_act!r}")
+    if bool(cfg["attention_bias"]) != mcfg.qkv_bias:
+        raise ValueError(f"attention_bias {cfg['attention_bias']} is not the "
+                         f"preset's qkv_bias {mcfg.qkv_bias}")
+    return mcfg
 
 
 def abstract_params(mcfg):

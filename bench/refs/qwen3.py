@@ -18,6 +18,10 @@ Matrix products run at `Precision.HIGHEST`. With `mode="fp8"` every
 product's inputs are rounded through float8 e4m3 with a per-tensor scale:
 that is the control, the step below the bfloat16 the configuration states.
 Attention runs in blocks of query rows so that a 16k-token sequence fits.
+
+Beside the forward, loss and training step, the module gives the harness
+what it needs of the architecture (`bench/weights.py`, `bench/counts.py`):
+`sizes`, the leaf tables of `leaf_tables` and `layer_flops_per_token`.
 """
 from __future__ import annotations
 
@@ -27,12 +31,63 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.counts import attn_ops_per_token
+
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 DENOM_EPS = 1e-6
 QK_EPS = 1e-6
 QBLOCK = 256
 LOSS_BLOCK = 512
+
+# (path, shape from the sizes, init) of the top-level leaves and of one
+# layer's leaves, in the program's tree: embed, final_norm, and
+# blocks_0/{norm1,mixer,norm2,ffn} with the layers stacked on axis 0.
+# Tied embeddings: no separate output head.
+_TOP = (
+    ("embed", lambda s: (s["V"], s["d"]), "normal"),
+    ("final_norm/scale", lambda s: (s["d"],), "ones"),
+)
+_LAYER = (
+    ("norm1/scale", lambda s: (s["d"],), "ones"),
+    ("mixer/wq", lambda s: (s["d"], s["hq"], s["hd"]), "normal"),
+    ("mixer/wk", lambda s: (s["d"], s["hkv"], s["hd"]), "normal"),
+    ("mixer/wv", lambda s: (s["d"], s["hkv"], s["hd"]), "normal"),
+    ("mixer/wo", lambda s: (s["hq"], s["hd"], s["d"]), "normal"),
+    ("mixer/q_norm_scale", lambda s: (s["hd"],), "ones"),
+    ("mixer/k_norm_scale", lambda s: (s["hd"],), "ones"),
+    ("norm2/scale", lambda s: (s["d"],), "ones"),
+    ("ffn/wi_gate", lambda s: (s["d"], s["ff"]), "normal"),
+    ("ffn/wi_up", lambda s: (s["d"], s["ff"]), "normal"),
+    ("ffn/wo", lambda s: (s["ff"], s["d"]), "normal"),
+)
+
+
+def sizes(cfg: dict) -> dict:
+    """The sizes the weights and the reference need, from a configuration
+    file's published keys."""
+    return {"V": cfg["vocab_size"], "d": cfg["hidden_size"],
+            "L": cfg["num_hidden_layers"], "hq": cfg["num_attention_heads"],
+            "hkv": cfg["num_key_value_heads"], "hd": cfg["head_dim"],
+            "ff": cfg["intermediate_size"], "theta": float(cfg["rope_theta"]),
+            "eps": float(cfg["rms_norm_eps"]),
+            "init": float(cfg["initializer_range"])}
+
+
+def leaf_tables(s: dict) -> dict:
+    """The top-level leaves, one table per `dense_{i}` (none) and one per
+    `blocks_{j}` (a single pattern entry)."""
+    return {"top": _TOP, "dense": (), "blocks": (_LAYER,)}
+
+
+def layer_flops_per_token(s: dict) -> float:
+    """Forward FLOPs of one decoder block for one token: the projections,
+    the SwiGLU MLP and fastmax's update and combine."""
+    d, hq, hkv, hd, ff = s["d"], s["hq"], s["hkv"], s["hd"], s["ff"]
+    proj = 2.0 * d * hd * (2 * hq + 2 * hkv)
+    mlp = 2.0 * 3 * d * ff
+    return proj + mlp + attn_ops_per_token(hkv, hd, hd) \
+        + attn_ops_per_token(hq, hd, hd)
 
 
 def _round(x, mode):

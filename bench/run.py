@@ -7,7 +7,12 @@ file, its traffic mix (`bench/traffic/<traffic>.json`), its check limits
 (`bench/cells/<name>.json`), its reference (`bench/refs/<reference>.py`,
 named by the configuration) and each per-layer metric's reader
 (`bench/metrics/<metric>.py`) are found by name, so a new cell, mix,
-configuration or metric is new files and entries only.
+configuration or metric is new files and entries only. All that is
+particular to an architecture lives in its configuration file (published
+keys, `run.arch`, `run.program`, the routing lines `run.routes` expects)
+and its reference module, which beside the plain forward gives the sizes,
+the weights' leaf tables and the FLOPs per token; the drivers and
+`program.py` name no architecture.
 
 With `--trace 0` the result line carries the cell's end-to-end metrics;
 with `--trace 1` the first seconds of the window are traced and the line
@@ -79,8 +84,7 @@ class Cell:
                              f"{workload['traffic']}.json")
         self.limits = load_json(root, "bench", "cells",
                                 f"{workload['name']}.json")
-        from bench.weights import sizes
-        self.sizes = sizes(self.cfg)
+        self.sizes = self.reference().sizes(self.cfg)
         self.seed = int(seed)
         self.seconds = float(seconds)
         self.trace = bool(trace)
@@ -109,7 +113,8 @@ class Cell:
     def weight_maker(self):
         from bench.weights import weight_maker
         if not hasattr(self, "_maker"):
-            self._maker = weight_maker(self.sizes, self.cfg["torch_dtype"])
+            self._maker = weight_maker(self.reference(), self.sizes,
+                                       self.cfg["torch_dtype"])
         return self._maker
 
     def reference(self):

@@ -86,9 +86,7 @@ def run(ctx):
     rec = TickRecorder(eng._tick_fn)
     eng._tick_fn = rec
     _warm(eng, rec, eng.chunk, s["V"], ctx.seed)
-    ctx.routes.check(expect=(
-        "decode: fastmax-kernel native-state kernel",
-        "prefill: fastmax-kernel resumable (offset) chunk, kernel seeded"))
+    ctx.routes.check(expect=run_cfg.get("routes", ()))
     reqs = traffic.serve_requests(mix, ctx.seed, s["V"])
     longest = max(len(r.prompt) + r.max_new for r in reqs)
     if longest > eng.slots.max_len:
@@ -192,7 +190,8 @@ def run(ctx):
         (a, ea), (b, eb) = trace_counts["start"], trace_counts["end"]
         toks = (b["prefill_tokens"] - a["prefill_tokens"]
                 + b["decode_tokens"] - a["decode_tokens"])
-        ctx.model_flops = counts.forward_flops(s, toks, eb - ea)
+        ctx.model_flops = counts.forward_flops(ctx.reference(), s, toks,
+                                              eb - ea)
         ctx.step_span = "bench.engine.step"
 
     # -- correctness: a sample of finished requests against the reference --

@@ -75,7 +75,7 @@ def run(ctx):
     master = params if opt_state.master is None else opt_state.master
     change = _floats(_change_norms(master, make(ctx.key)))
     del master
-    ctx.routes.check(expect=())
+    ctx.routes.check(expect=cfg["run"].get("routes", ()))
     say(f"set-up steps: losses {losses}, gradient norms {gnorms}")
 
     steps, bad, i = 0, 0, k
@@ -132,7 +132,8 @@ def run(ctx):
     require_kernels(text, ["fastmax_causal_p2", "fastmax_causal_bwd_p2"])
     ctx.kernel_calls = counts.kernel_calls(text)
     if trace_steps:
-        ctx.model_flops = trace_steps * counts.train_flops(s, tokens_per_step)
+        ctx.model_flops = trace_steps * counts.train_flops(
+            ctx.reference(), s, tokens_per_step)
         ctx.step_span = "bench.train.step"
     names = [jax.tree_util.keystr(path) for path, _ in
              jax.tree_util.tree_flatten_with_path(params)[0]]

@@ -4,10 +4,17 @@ The benchmark, not the program, makes the weights: one jitted call turns
 the seed into the whole parameter tree in the dtype it is served in. The
 plain reference calls the same compiled function again after the window,
 so both sides see bit-identical weights without the reference taking
-anything the program made. Each leaf has its own key, folded from the seed,
-the leaf's index and its layer. Every matrix, the (tied) embedding
-included, is normal with the configuration's published
-`initializer_range` as its standard deviation; norm scales are one.
+anything the program made. The tree is whatever the cell's reference
+describes (`leaf_tables`): top-level leaves, `dense_{i}` layers and
+`blocks_{j}` groups stacked on axis 0, each leaf a `(path, shape from the
+sizes, init)` with init `normal` (the configuration's published
+`initializer_range` as its standard deviation), `ones` or `zeros`.
+
+Each leaf has its own key, folded from the seed. Top-level leaf t takes
+fold t, skipping fold 1, which is the layers': leaf i of layer l takes
+`fold_in(fold_in(fold_in(key, 1), l), i)`, where `dense_i` is layer i and
+group g of `blocks_j` is layer `len(dense) + g * len(blocks) + j`, so no
+two leaves share a key.
 
 The embedding's scale matters to the check: drawn at unit scale, as the
 program's own initializer draws it, the last token's embedding dominates
@@ -23,32 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# (name, shape from the sizes, is a matrix) of one layer's leaves, in the
-# program's tree: blocks_0/{norm1,mixer,norm2,ffn}
-_LAYER = (
-    ("norm1/scale", lambda s: (s["d"],), False),
-    ("mixer/wq", lambda s: (s["d"], s["hq"], s["hd"]), True),
-    ("mixer/wk", lambda s: (s["d"], s["hkv"], s["hd"]), True),
-    ("mixer/wv", lambda s: (s["d"], s["hkv"], s["hd"]), True),
-    ("mixer/wo", lambda s: (s["hq"], s["hd"], s["d"]), True),
-    ("mixer/q_norm_scale", lambda s: (s["hd"],), False),
-    ("mixer/k_norm_scale", lambda s: (s["hd"],), False),
-    ("norm2/scale", lambda s: (s["d"],), False),
-    ("ffn/wi_gate", lambda s: (s["d"], s["ff"]), True),
-    ("ffn/wi_up", lambda s: (s["d"], s["ff"]), True),
-    ("ffn/wo", lambda s: (s["ff"], s["d"]), True),
-)
-
-
-def sizes(cfg: dict) -> dict:
-    """The sizes the weights and the reference need, from a configuration
-    file's published keys."""
-    return {"V": cfg["vocab_size"], "d": cfg["hidden_size"],
-            "L": cfg["num_hidden_layers"], "hq": cfg["num_attention_heads"],
-            "hkv": cfg["num_key_value_heads"], "hd": cfg["head_dim"],
-            "ff": cfg["intermediate_size"], "theta": float(cfg["rope_theta"]),
-            "eps": float(cfg["rms_norm_eps"]),
-            "init": float(cfg["initializer_range"])}
+LAYERS_FOLD = 1
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -64,17 +46,21 @@ def seed_key(seed: int) -> jax.Array:
             return key
 
 
-def layer_weights(key, layer, s: dict) -> dict:
+def _leaf(key, shape, init, s: dict):
+    if init == "normal":
+        return jax.random.normal(key, shape, jnp.float32) * s["init"]
+    if init == "ones":
+        return jnp.ones(shape, jnp.float32)
+    if init == "zeros":
+        return jnp.zeros(shape, jnp.float32)
+    raise ValueError(f"unknown init {init!r}")
+
+
+def layer_weights(key, layer, table, s: dict) -> dict:
     """One layer's leaves in float32, as a flat {path: array} dict."""
-    lk = jax.random.fold_in(jax.random.fold_in(key, 1), layer)
-    out = {}
-    for i, (name, shape, matrix) in enumerate(_LAYER):
-        if matrix:
-            out[name] = jax.random.normal(jax.random.fold_in(lk, i),
-                                          shape(s), jnp.float32) * s["init"]
-        else:
-            out[name] = jnp.ones(shape(s), jnp.float32)
-    return out
+    lk = jax.random.fold_in(jax.random.fold_in(key, LAYERS_FOLD), layer)
+    return {path: _leaf(jax.random.fold_in(lk, i), shape(s), init, s)
+            for i, (path, shape, init) in enumerate(table)}
 
 
 def _nest(flat: dict) -> dict:
@@ -88,22 +74,33 @@ def _nest(flat: dict) -> dict:
     return tree
 
 
-def make_weights(key, *, s: dict, dtype) -> dict:
-    """The whole tree: embed, blocks_0 (layers stacked on axis 0),
-    final_norm. Tied embeddings: no separate output head."""
-    embed = jax.random.normal(jax.random.fold_in(key, 0), (s["V"], s["d"]),
-                              jnp.float32) * s["init"]
-    layers = jax.vmap(lambda l: layer_weights(key, l, s))(
-        jnp.arange(s["L"], dtype=jnp.uint32))
-    tree = {"embed": embed, "blocks_0": _nest(layers),
-            "final_norm": {"scale": jnp.ones((s["d"],), jnp.float32)}}
-    return jax.tree.map(lambda x: x.astype(dtype), tree)
+def make_weights(key, *, tables: dict, s: dict, dtype) -> dict:
+    """The whole tree that `tables` (a reference's `leaf_tables(s)`)
+    describes, in `dtype`."""
+    flat = {}
+    for t, (path, shape, init) in enumerate(tables["top"]):
+        fold = t if t < LAYERS_FOLD else t + 1
+        flat[path] = _leaf(jax.random.fold_in(key, fold), shape(s), init, s)
+    dense, blocks = tables["dense"], tables["blocks"]
+    for i, table in enumerate(dense):
+        flat[f"dense_{i}"] = _nest(layer_weights(key, i, table, s))
+    if blocks and (s["L"] - len(dense)) % len(blocks):
+        raise ValueError(f"{s['L'] - len(dense)} stacked layers do not "
+                         f"divide into groups of {len(blocks)}")
+    for j, table in enumerate(blocks):
+        layers = jnp.arange(len(dense) + j, s["L"], len(blocks),
+                            dtype=jnp.uint32)
+        flat[f"blocks_{j}"] = _nest(jax.vmap(
+            lambda l, table=table: layer_weights(key, l, table, s))(layers))
+    return jax.tree.map(lambda x: x.astype(dtype), _nest(flat))
 
 
-def weight_maker(s: dict, dtype):
-    """The jitted maker: key -> tree. Calling the same maker twice gives
+def weight_maker(ref, s: dict, dtype):
+    """The jitted maker of the tree that the reference module `ref`
+    describes: key -> tree. Calling the same maker twice gives
     bit-identical trees."""
-    return jax.jit(functools.partial(make_weights, s=s, dtype=jnp.dtype(dtype)))
+    return jax.jit(functools.partial(make_weights, tables=ref.leaf_tables(s),
+                                     s=s, dtype=jnp.dtype(dtype)))
 
 
 def check_layout(tree, want) -> None:

@@ -30,20 +30,20 @@ def test_forward_count_matches_compiled_matmuls():
     """Widening the MLP and the vocabulary adds exactly the FLOPs the
     count says; attention's matrix products are the same on both sides."""
     from bench import counts, program
-    from bench.weights import sizes
 
+    ref = tiny_cell.load_ref()
     n = 32
     cfg_file = dict(tiny_cell.TINY)
     base = program.model_config(cfg_file)
     wide = dict(cfg_file, intermediate_size=256, vocab_size=1024)
     f0 = _compiled_matmul_flops(base, n)
     f1 = _compiled_matmul_flops(program.model_config(wide), n)
-    s0, s1 = sizes(cfg_file), sizes(wide)
+    s0, s1 = ref.sizes(cfg_file), ref.sizes(wide)
     assert f1 - f0 == pytest.approx(
-        counts.forward_flops(s1, n, n) - counts.forward_flops(s0, n, n),
-        rel=1e-9)
+        counts.forward_flops(ref, s1, n, n)
+        - counts.forward_flops(ref, s0, n, n), rel=1e-9)
     # the projections and the MLP alone stay under what XLA compiles
-    no_attn = n * s0["L"] * (counts.layer_flops_per_token(s0)
+    no_attn = n * s0["L"] * (ref.layer_flops_per_token(s0)
                              - counts.attn_ops_per_token(s0["hkv"], 16, 16)
                              - counts.attn_ops_per_token(s0["hq"], 16, 16))
     assert no_attn + n * counts.logits_flops(s0) <= f0
@@ -90,9 +90,10 @@ def test_kernel_cost_from_shapes():
 
 def test_train_flops_are_three_forwards():
     from bench import counts
-    from bench.weights import sizes
-    s = sizes(tiny_cell.TINY)
-    assert counts.train_flops(s, 64) == 3 * counts.forward_flops(s, 64, 64)
+    ref = tiny_cell.load_ref()
+    s = ref.sizes(tiny_cell.TINY)
+    assert counts.train_flops(ref, s, 64) \
+        == 3 * counts.forward_flops(ref, s, 64, 64)
 
 
 def test_unknown_device_kind_raises():

@@ -8,6 +8,7 @@ import re
 import sys
 
 import numpy as np
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import tiny_cell  # noqa: E402
@@ -42,6 +43,28 @@ def test_new_train_cell_runs_from_new_files(tmp_path):
     assert set(out["metrics"]) == {"train_tok_s", "setup_s"}
     assert set(checks) == {"loss_gap", "gnorm_gap", "grad_norm_gap",
                            "update_norm_gap"}
+
+
+@pytest.mark.parametrize("mix", ["tiny_closed", "tiny_train"])
+def test_second_architecture_cell_runs_from_new_files(tmp_path, mix):
+    """granite-20b's layout (untied head, no qk-norm, one kv head): its
+    configuration, reference and mix are new files under the root."""
+    out, _ = tiny_cell.run_tiny(tmp_path, mix, arch="granite",
+                                seed=2**31 + 21)
+    assert out["correct"] is True
+    assert out["attempted"] > 0 and out["failed"] == 0
+    kind = tiny_cell.MIXES[mix]["kind"]
+    assert set(out["checks"]) == set(tiny_cell.LIMITS[kind])
+    assert os.path.exists(tmp_path / "bench" / "refs" / "granite.py")
+
+
+@pytest.mark.parametrize("mix", ["tiny_closed", "tiny_train"])
+def test_second_architecture_control_fails(tmp_path, mix):
+    """Its reference in float8, put in the program's place, is judged not
+    correct: the fixture reference separates the two readings."""
+    out, _ = tiny_cell.run_tiny(tmp_path, mix, arch="granite", seed=5,
+                                control="fp8")
+    assert out["correct"] is False
 
 
 def test_traffic_is_a_pure_function_of_the_seed():

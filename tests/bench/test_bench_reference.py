@@ -17,13 +17,13 @@ def setup():
     import jax.numpy as jnp
 
     from bench import program
-    from bench.weights import check_layout, seed_key, sizes, weight_maker
+    from bench.weights import check_layout, seed_key, weight_maker
 
-    s = sizes(tiny_cell.TINY)
-    cfg = program.model_config(tiny_cell.TINY)
-    w = weight_maker(s, "float32")(seed_key(2**31 + 12345))
-    check_layout(w, program.abstract_params(cfg))
     ref = tiny_cell.load_ref()
+    s = ref.sizes(tiny_cell.TINY)
+    cfg = program.model_config(tiny_cell.TINY)
+    w = weight_maker(ref, s, "float32")(seed_key(2**31 + 12345))
+    check_layout(w, program.abstract_params(cfg))
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, s["V"], (2, 40)).astype(np.int32)
     targets = rng.integers(0, s["V"], (2, 40)).astype(np.int32)

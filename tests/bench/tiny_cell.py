@@ -1,7 +1,10 @@
 """A throwaway cell at a size the CPU runs in seconds, written as new files
 under a temporary root, the way a later change adds a cell: a
 configuration, a traffic mix, a limits file, a reference and a per-layer
-metric reader, plus a BENCHMARK.json entry naming them."""
+metric reader, plus a BENCHMARK.json entry naming them. The configuration
+is qwen3's smoke size (`TINY`) or, as a second architecture, the program's
+granite-20b smoke preset (`GRANITE`: no qk-norm, one kv head, an untied
+head) with its reference, `granite_ref.py`, a fixture beside this file."""
 from __future__ import annotations
 
 import json
@@ -24,6 +27,21 @@ TINY = {
     "tie_word_embeddings": True, "torch_dtype": "float32", "vocab_size": 512,
     "run": {"arch": "qwen3-1.7b", "attention": "fastmax2", "chunk_size": 16,
             "max_slots": 3, "max_len": 256}}
+
+GRANITE = {
+    "reference": "granite", "attention_bias": False, "head_dim": 16,
+    "hidden_act": "silu", "hidden_size": 64, "initializer_range": 0.02,
+    "intermediate_size": 128, "num_attention_heads": 4,
+    "num_hidden_layers": 2, "num_key_value_heads": 1, "rms_norm_eps": 1e-5,
+    "rope_theta": 10000, "tie_word_embeddings": False,
+    "torch_dtype": "float32", "vocab_size": 512,
+    "run": {"arch": "granite-20b", "attention": "fastmax2", "chunk_size": 16,
+            "max_slots": 3, "max_len": 256}}
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+# reference name -> its source file
+REFS = {"qwen3": os.path.join(ROOT, "bench", "refs", "qwen3.py"),
+        "granite": os.path.join(HERE, "granite_ref.py")}
 
 # wide enough that the layers, not the embedding, decide the greedy token:
 # there a lower precision flips some served tokens
@@ -67,37 +85,39 @@ def read(r):
 '''
 
 
-def make_root(tmp, mix_name: str) -> tuple:
+def make_root(tmp, mix_name: str, arch: str = "tiny") -> tuple:
     """Writes the cell's files under `tmp`; returns (spec, workload)."""
     mix = MIXES[mix_name]
-    cell = f"tiny-{mix_name}"
+    cfg = {"tiny": TINY, "granite": GRANITE}[arch]
+    cell = f"{arch}-{mix_name}"
     b = os.path.join(tmp, "bench")
     for d in ("configs", "traffic", "cells", "metrics", "refs"):
         os.makedirs(os.path.join(b, d), exist_ok=True)
-    with open(os.path.join(b, "configs", "tiny.json"), "w") as f:
-        json.dump(TINY, f)
+    with open(os.path.join(b, "configs", f"{arch}.json"), "w") as f:
+        json.dump(cfg, f)
     with open(os.path.join(b, "traffic", f"{mix_name}.json"), "w") as f:
         json.dump(mix, f)
     with open(os.path.join(b, "cells", f"{cell}.json"), "w") as f:
         json.dump({"limits": LIMITS[mix["kind"]]}, f)
     with open(os.path.join(b, "metrics", "tiny_metric.py"), "w") as f:
         f.write(READER)
-    shutil.copy(os.path.join(ROOT, "bench", "refs", "qwen3.py"),
-                os.path.join(b, "refs", "qwen3.py"))
+    # a reference may build on qwen3's, so that one is always there
+    for ref in {"qwen3", cfg["reference"]}:
+        shutil.copy(REFS[ref], os.path.join(b, "refs", f"{ref}.py"))
     shutil.copy(os.path.join(ROOT, "bench", "metrics", "idle_share.decode.py"),
                 os.path.join(b, "metrics", "idle_share.decode.py"))
-    moves = "train_tok_s" if mix["kind"] == "train" else "serve_tok_s"
+    train = mix["kind"] == "train"
+    moves = "train_tok_s" if train else "serve_tok_s"
+    serving, training = ([], [cell]) if train else ([cell], [])
     spec = {
-        "configs": [{"name": "tiny", "file": "bench/configs/tiny.json"}],
-        "workloads": [{"name": cell, "config": "tiny", "traffic": mix_name,
+        "configs": [{"name": arch, "file": f"bench/configs/{arch}.json"}],
+        "workloads": [{"name": cell, "config": arch, "traffic": mix_name,
                        "chips": 1}],
         "end_to_end": [
-            {"name": "serve_tok_s", "unit": "tokens/s",
-             "workloads": ["tiny-tiny_closed"]},
-            {"name": "itl_p95_ms", "unit": "ms",
-             "workloads": ["tiny-tiny_closed"]},
+            {"name": "serve_tok_s", "unit": "tokens/s", "workloads": serving},
+            {"name": "itl_p95_ms", "unit": "ms", "workloads": serving},
             {"name": "train_tok_s", "unit": "tokens/s",
-             "workloads": ["tiny-tiny_train"]},
+             "workloads": training},
             {"name": "setup_s", "unit": "s"}],
         "per_layer": [
             {"name": "tiny_metric", "unit": "%", "moves": moves,
@@ -109,14 +129,15 @@ def make_root(tmp, mix_name: str) -> tuple:
     return spec, spec["workloads"][0]
 
 
-def run_tiny(tmp, mix_name, *, seed=7, seconds=1.0, trace=0, control=None):
+def run_tiny(tmp, mix_name, *, arch="tiny", seed=7, seconds=1.0, trace=0,
+             control=None):
     """Drive the whole run of the throwaway cell on the CPU (no device
     guard; the kernel-route checks, which need a TPU, pass through)."""
     import time
 
     from bench import device, run
 
-    spec, workload = make_root(tmp, mix_name)
+    spec, workload = make_root(tmp, mix_name, arch)
     cell = run.Cell(spec, workload, seed, seconds, trace,
                     t_start=time.perf_counter(), root=str(tmp))
     cell.routes = _NoRoutes()
@@ -156,5 +177,4 @@ def lengths(reqs):
 def load_ref():
     """The reference module, loaded by path as the harness loads it."""
     from bench import run
-    return run.load_module(os.path.join(ROOT, "bench", "refs", "qwen3.py"),
-                           "bench_ref_qwen3")
+    return run.load_module(REFS["qwen3"], "bench_ref_qwen3")
